@@ -27,7 +27,7 @@ def run() -> None:
     for (M, N, K) in SHAPES:
         plan = plan_gemm_tiling(M, N, K, dtype_bytes=4)
         bm, bn, bk = plan.block
-        vmem = (bm * bk + bk * bn + bm * bn) * 4
+        vmem = plan.vmem_bytes(4)
         assert bm * bk + bk * bn + bm * bn <= hw.sram_words
         a = (jax.random.normal(jax.random.PRNGKey(0), (M, K), jnp.float32)
              * 0.05)

@@ -19,6 +19,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
@@ -33,7 +34,8 @@ def main():
     assert len(jax.devices()) == 8, jax.devices()
     cfg = get_config("llama3-8b", smoke=True)
     model = build_model(cfg)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     params_host = model.init_params(jax.random.PRNGKey(0))
     params_sh = param_shardings(params_host, mesh, mode="fsdp")
@@ -71,7 +73,8 @@ def main():
     with tempfile.TemporaryDirectory() as d:
         mgr = CheckpointManager(d, async_save=False)
         mgr.save(10, params)
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                              axis_types=(AxisType.Auto,) * 2)
         sh2 = param_shardings(params_host, mesh2, mode="fsdp")
         restored, step0 = mgr.restore(
             jax.eval_shape(lambda: params_host), shardings=sh2)

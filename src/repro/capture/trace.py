@@ -43,10 +43,7 @@ from typing import Any, Callable, Iterator
 
 import jax
 
-try:                                    # public-API Literal when available
-    from jax.extend.core import Literal
-except ImportError:                     # pragma: no cover - old jax
-    from jax.core import Literal  # type: ignore
+from jax.extend.core import Literal
 
 # Shape-preserving elementwise primitives a fused chain can stream
 # through (plus comparisons/select so relu-style gates classify).
@@ -62,7 +59,7 @@ ELEMENTWISE_PRIMS = frozenset({
 
 # Call-like primitives whose bodies are inlined for elementwise analysis.
 _CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "custom_jvp_call",
+    "jit", "pjit", "closed_call", "core_call", "custom_jvp_call",
     "custom_vjp_call", "custom_vjp_call_jaxpr", "remat", "checkpoint",
     "remat2", "custom_lin",
 })

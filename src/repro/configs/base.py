@@ -82,15 +82,16 @@ class ArchConfig:
     # upcasts to f32 inside log_softmax); "bfloat16" halves the largest
     # activation tensor of big-vocab models
     logits_dtype: str = "float32"
-    # route the RWKV6/Mamba2 chunked scans through the Pallas kernels
-    # (kernels/wkv6.py, kernels/mamba2_ssd.py); interpret mode off-TPU
+    # route the RWKV6 chunked scan through the Pallas kernel
+    # (kernels/wkv6.py; no model calls kernels/mamba2_ssd.py yet);
+    # interpret mode on the CPU
     use_pallas_scan: bool = False
     # MoE dispatch: "dense" (one-hot, static, E/top_k redundant compute)
     # or "gathered" (sort-based capacity buckets, §Perf hillclimb B3)
     moe_dispatch: str = "dense"
     # route gated-MLP blocks through the GOMA-chain-planned fused Pallas
     # kernel (kernels/goma_fused.py): gate/up -> silu* -> down with the
-    # intermediate strip held in VMEM scratch; interpret mode off-TPU.
+    # intermediate strip held in VMEM scratch; interpret mode on the CPU.
     # Token-identical to the unfused composition (DESIGN.md §Fusion).
     fused_mlp: bool = False
 

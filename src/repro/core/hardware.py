@@ -60,6 +60,9 @@ class AcceleratorSpec:
     spatial_equality: bool = True  # eq. 29 as equality (100% PE util)
     # fixed spatial shape, e.g. TPU MXU = (128,128,1); None = free fanout
     fixed_spatial: tuple[int, int, int] | None = None
+    # per-axis SRAM-tile alignment: each L1 tile is a multiple of it or
+    # the whole extent (a kernel's block-shape rule); None = any divisor
+    l1_align: tuple[int, int, int] | None = None
 
     def capacity(self, level: int) -> int:
         return {1: self.sram_words, 3: self.rf_words}[level]
@@ -118,8 +121,9 @@ TPUV1_LIKE = AcceleratorSpec(
 # --- TPU-v5e-like spec used by core/tpu_mapping.py to plan Pallas tiling ---
 # HBM -> VMEM -> (MXU 128x128 systolic + accumulators).  The MXU is a
 # hard-wired x*y spatial tile: fixed_spatial pins L-hat^(2-3) = (128,128,1).
-# VMEM ~= 16 MiB/core is budgeted at 60% for mapper-managed operands (the
-# rest: semaphores, double-buffering headroom, spills).
+# VMEM ~= 16 MiB/core is budgeted at 60% for the kernels' pipeline buffers
+# and accumulators (the rest: Mosaic's own scratch — dot results, spills,
+# semaphores); tpu_mapping.tpu_spec charges the double buffering.
 TPUV5E_LIKE = AcceleratorSpec(
     name="tpuv5e-like",
     sram_words=int(16 * 1024 * 1024 * 0.6),   # VMEM words (int8)

@@ -263,12 +263,15 @@ _KEEP_FIRST = np.array([True, False])
 
 
 @functools.lru_cache(maxsize=1024)
-def _chain_arrays(L0d: int, fixed_s: int | None, fixed_l1: int | None = None):
+def _chain_arrays(L0d: int, fixed_s: int | None, fixed_l1: int | None = None,
+                  align: int | None = None):
     """Variant-independent chain geometry of one axis extent: the divisor
     chains as int64 columns, the spatial values, and the s-group index
     partition with per-group dense l1-ranks.  Shared by all variant keys
     (and across solves).  ``fixed_l1`` restricts to chains whose SRAM
-    tile equals it (the chain solver's tiling-compatibility pin)."""
+    tile equals it (the chain solver's tiling-compatibility pin);
+    ``align`` to SRAM tiles that are its multiples or the whole extent
+    (``AcceleratorSpec.l1_align``)."""
     arr = np.array(divisor_chains(L0d), dtype=np.int64)
     l1, l2, l3 = (np.ascontiguousarray(arr[:, 0]),
                   np.ascontiguousarray(arr[:, 1]),
@@ -276,6 +279,9 @@ def _chain_arrays(L0d: int, fixed_s: int | None, fixed_l1: int | None = None):
     s = l2 // l3
     if fixed_l1 is not None:
         mask = l1 == fixed_l1
+        l1, l2, l3, s = l1[mask], l2[mask], l3[mask], s[mask]
+    if align is not None:
+        mask = (l1 % align == 0) | (l1 == L0d)
         l1, l2, l3, s = l1[mask], l2[mask], l3[mask], s[mask]
     if fixed_s is not None:
         mask = s == fixed_s
@@ -291,20 +297,22 @@ def _chain_arrays(L0d: int, fixed_s: int | None, fixed_l1: int | None = None):
 
 def _axis_cands(kind: str, L0d: int, ert: Ert, w01: bool, w12: bool,
                 r1: bool, r3: bool, fixed_s: int | None,
-                fixed_l1: int | None = None) -> _AxisCands:
+                fixed_l1: int | None = None,
+                align: int | None = None) -> _AxisCands:
     # Canonical variant key: the walking bits only enter the energy under
     # the matching residency bit (w01 via the r1 terms, w12 via the r3
     # compensation/rho terms, for both axis kinds), so 16 raw keys
     # collapse to 9 distinct candidate arrays.
     w01, w12 = w01 and r1, w12 and r3
-    key = (kind, L0d, ert, w01, w12, r1, r3, fixed_s, fixed_l1)
+    key = (kind, L0d, ert, w01, w12, r1, r3, fixed_s, fixed_l1, align)
     c = _AXIS_MEMO.get(key)
     if c is not None:
         _AXIS_MEMO.move_to_end(key)
         _REG.inc("solver.axis_cache.hits")
         return c
     _REG.inc("solver.axis_cache.misses")
-    l1, l2, l3, s, s_vals, groups = _chain_arrays(L0d, fixed_s, fixed_l1)
+    l1, l2, l3, s, s_vals, groups = _chain_arrays(L0d, fixed_s, fixed_l1,
+                                                  align)
     g = _axis_energy_kind(kind, L0d, l1, l2, l3, w01, w12, r1, r3, ert)
     by_s: dict[int, np.ndarray] = {}
     min_g_by_s: dict[int, float] = {}
@@ -841,8 +849,10 @@ def _solve_impl(gemm: Gemm, hw: AcceleratorSpec, *,
                        if hw.fixed_spatial is not None else None)
             fl1 = (fixed_l1[AXES.index(axis)]
                    if fixed_l1 is not None else None)
+            align = (hw.l1_align[AXES.index(axis)]
+                     if hw.l1_align is not None else None)
             c = _axis_cands(kind, gemm.dim(axis), hw.ert, w01, w12, r1, r3,
-                            fixed_s, fl1)
+                            fixed_s, fl1, align)
             local_cands[key] = c
         return c
 

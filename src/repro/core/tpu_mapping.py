@@ -26,6 +26,12 @@ from .hardware import TPUV5E_LIKE, AcceleratorSpec
 from .solver import SolveResult, solve
 
 MXU = 128
+# VMEM of one v5e TensorCore as TPUV5E_LIKE describes it: the scoped limit
+# every GOMA kernel declares to Mosaic.  The planner hands at most
+# VMEM_BUDGET_BYTES of it to pipeline buffers and accumulators; the rest
+# is Mosaic's own scratch.
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+VMEM_BUDGET_BYTES = TPUV5E_LIKE.sram_words      # 8-bit words = bytes
 
 # --- plan-store read-through ------------------------------------------------
 # When a plan store is installed (explicitly via set_plan_store or through
@@ -99,15 +105,33 @@ class TpuTilePlan:
         sizes = {"m": pm // bm, "n": pn // bn, "k": pk // bk}
         return tuple(sizes[g] for g in self.grid_order)
 
+    def vmem_bytes(self, dtype_bytes: int) -> int:
+        """VMEM goma_gemm allocates for this plan: two pipeline buffers of
+        each block, plus the f32 accumulator when k takes several steps."""
+        bm, bn, bk = self.block
+        acc = 4 * bm * bn if self.padded[2] > bk else 0
+        return 2 * dtype_bytes * (bm * bk + bk * bn + bm * bn) + acc
+
 
 def tpu_spec(dtype_bytes: int = 2,
              base: AcceleratorSpec = TPUV5E_LIKE) -> AcceleratorSpec:
-    """Rescale the v5e spec's word capacities to the compute dtype."""
+    """The v5e spec in the terms the Pallas kernels realize.
+
+    VMEM: the base budget is in bytes.  The Pallas pipeline holds two
+    buffers of every block and goma_gemm adds an f32 accumulator for its
+    output block, so one word of the solver's tile footprint costs at most
+    ``2 * dtype_bytes + 4`` bytes; in those words every feasible tiling's
+    ``TpuTilePlan.vmem_bytes`` fits the budget.
+
+    Alignment: Mosaic tiles the last two dims of a block in (8, 128)
+    units, so every VMEM tile is an MXU multiple or the whole padded
+    extent (K below one MXU is left unpadded)."""
     return dataclasses.replace(
         base,
         name=f"{base.name}-{dtype_bytes}B",
-        sram_words=base.sram_words // dtype_bytes,
+        sram_words=base.sram_words // (2 * dtype_bytes + 4),
         rf_words=base.rf_words,
+        l1_align=(MXU, MXU, MXU),
     )
 
 
@@ -147,9 +171,10 @@ class FusedTilePlan:
     ``(K,FF)``, Wd ``(FF,N2)`` and the intermediate ``(bm, FF)`` strip
     held in VMEM scratch.
 
-    ``fused=False`` records that no strip height was residency-feasible
-    (or the chain solver kept the unfused pair): callers run the
-    two-``goma_matmul`` composition instead.
+    ``fused=False`` records that no strip height was residency-feasible,
+    that the chain solver kept the unfused pair, or that the fused
+    kernel's blocks (Wd's whole block among them) exceed the VMEM budget:
+    callers run per-GEMM ``gemm`` calls instead.
     """
 
     M: int
@@ -168,6 +193,15 @@ class FusedTilePlan:
     def grid(self) -> tuple[int, int]:
         pm, pff, pk, pn2 = self.padded
         return (pm // self.bm, pk // self.bk)
+
+    def vmem_bytes(self, dtype_bytes: int) -> int:
+        """VMEM goma_fused allocates for this plan: two pipeline buffers of
+        each block — Wd's whole (pff, pn2) block among them — plus the two
+        f32 (bm, pff) strips."""
+        pm, pff, pk, pn2 = self.padded
+        bm, bk = self.bm, self.bk
+        blocks = bm * bk + 2 * bk * pff + pff * pn2 + bm * pn2
+        return 2 * dtype_bytes * blocks + 2 * 4 * bm * pff
 
     def producer_plan(self) -> TpuTilePlan:
         """The equivalent single-GEMM tiling of one producer link — the
@@ -246,16 +280,20 @@ def _plan_fused_mlp(M: int, FF: int, K: int, N2: int, *,
         res = solve_chain(chain, hw, objective="energy",
                           allowed_walk01=("z",))
     cert = res.certificate
-    if cert.fused and res.producer_mapping is not None:
-        bm = int(res.producer_mapping.L1[0])
-        bk = int(res.producer_mapping.L1[2])
-    else:
-        bm, bk = 0, 0
-    return FusedTilePlan(M=M, FF=FF, K=K, N2=N2, padded=padded,
-                         fused=bool(cert.fused), bm=bm, bk=bk,
-                         objective=cert.objective,
+    plan = FusedTilePlan(M=M, FF=FF, K=K, N2=N2, padded=padded,
+                         fused=False, bm=0, bk=0,
+                         objective=cert.unfused_objective,
                          unfused_objective=cert.unfused_objective,
                          solve_time_s=cert.solve_time_s)
+    if cert.fused and res.producer_mapping is not None:
+        fused = dataclasses.replace(
+            plan, fused=True, bm=int(res.producer_mapping.L1[0]),
+            bk=int(res.producer_mapping.L1[2]), objective=cert.objective)
+        # the chain solve prices the strips but not Wd, which the kernel
+        # keeps whole in VMEM: a chain whose Wd does not fit stays unfused
+        if fused.vmem_bytes(dtype_bytes) <= VMEM_BUDGET_BYTES:
+            return fused
+    return plan
 
 
 @functools.lru_cache(maxsize=512)

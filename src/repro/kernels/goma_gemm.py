@@ -16,8 +16,13 @@ plan has no k tiling (nk == 1) each block's dot is complete, so the
 VMEM accumulator scratch and the flush epilogue are skipped entirely
 and the dot is written straight to the output block.
 
+VMEM: the kernel declares ``VMEM_LIMIT_BYTES`` to Mosaic; the planner
+sizes its blocks so that the double-buffered blocks and the accumulator
+(``TpuTilePlan.vmem_bytes``) fit the budget within it (core/tpu_mapping).
+
 Validated against ref.matmul_ref in interpret mode (CPU) over a
-shape/dtype sweep; compiled path targets real TPUs unchanged.
+shape/dtype sweep; tests/test_tpu_compile.py compiles the plans of real
+model widths for a v5e.
 """
 from __future__ import annotations
 
@@ -28,11 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.tpu_mapping import TpuTilePlan
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
+from ..core.tpu_mapping import VMEM_LIMIT_BYTES, TpuTilePlan
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_axis: int | None,
@@ -70,6 +71,9 @@ def goma_matmul(a: jnp.ndarray, b: jnp.ndarray, plan: TpuTilePlan,
     pos = {g: i for i, g in enumerate(order)}
     grid = plan.grid
     nk = pk // bk
+    # the accumulator carries one output block across consecutive grid
+    # steps only: a split reduction must be the innermost walk
+    assert nk == 1 or order[-1] == "k", plan
     k_axis = pos["k"] if nk > 1 else None
 
     def a_map(*idx):
@@ -81,13 +85,12 @@ def goma_matmul(a: jnp.ndarray, b: jnp.ndarray, plan: TpuTilePlan,
     def o_map(*idx):
         return (idx[pos["m"]], idx[pos["n"]])
 
-    kwargs = {}
-    if _CompilerParams is not None:
-        # m/n blocks are independent (parallel); k is the sequential
-        # reduction walk — ordered per the plan's grid order
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=tuple(
-                "arbitrary" if g == "k" else "parallel" for g in order))
+    # m/n blocks are independent (parallel); k is the sequential
+    # reduction walk — ordered per the plan's grid order
+    params = pltpu.CompilerParams(
+        dimension_semantics=tuple(
+            "arbitrary" if g == "k" else "parallel" for g in order),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
     if nk == 1:
         kernel = _matmul_kernel_single_k
         scratch = []
@@ -102,6 +105,6 @@ def goma_matmul(a: jnp.ndarray, b: jnp.ndarray, plan: TpuTilePlan,
         out_specs=pl.BlockSpec((bm, bn), o_map),
         out_shape=jax.ShapeDtypeStruct((pm, pn), out_dtype),
         scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
-        **kwargs,
     )(a, b)

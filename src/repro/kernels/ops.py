@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 ``gemm`` is the user-facing entry: it pads to the GOMA plan's MXU-aligned
-shape, dispatches the Pallas kernel, and slices the result back.  On
-non-TPU backends it runs the kernel in interpret mode (CPU correctness
-path) unless ``force_xla=True`` picks the plain XLA dot instead.
+shape, dispatches the Pallas kernel, and slices the result back.  On the
+CPU backend it runs the kernel in interpret mode (the correctness path)
+unless ``force_xla=True`` picks the plain XLA dot instead; on any other
+backend the kernel compiles, or fails to.
 """
 from __future__ import annotations
 
@@ -15,15 +16,17 @@ import jax.numpy as jnp
 from ..core.tpu_mapping import plan_fused_mlp, plan_gemm_tiling
 from ..obs.registry import get_registry
 from ..obs.tracing import span as _span
-from .goma_fused import ACTIVATIONS, goma_fused_matmul
+from .goma_fused import ACTIVATIONS, goma_combine, goma_fused_matmul
 from .goma_gemm import goma_matmul
 from .ref import matmul_ref
 
 _REG = get_registry()
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_default() -> bool:
+    """Interpret Pallas kernels on the CPU only, where Mosaic cannot
+    compile them; an accelerator never falls back to the interpreter."""
+    return jax.default_backend() == "cpu"
 
 
 def gemm(a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool | None = None,
@@ -66,7 +69,7 @@ def _gemm_jit(a: jnp.ndarray, b: jnp.ndarray, *,
     pm, pn, pk = plan.padded
     a_p = jnp.pad(a, ((0, pm - M), (0, pk - K)))
     b_p = jnp.pad(b, ((0, pk - K), (0, pn - N)))
-    itp = (not _on_tpu()) if interpret is None else interpret
+    itp = interpret_default() if interpret is None else interpret
     out = goma_matmul(a_p, b_p, plan, interpret=itp)
     return out[:M, :N]
 
@@ -86,20 +89,20 @@ def fused_mlp_composition(a: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
                           wd: jnp.ndarray, plan, *,
                           activation: str = "silu_mul",
                           interpret: bool | None = None) -> jnp.ndarray:
-    """The *unfused* two-``goma_matmul`` composition under the fused
+    """The *unfused* composition (producer ``goma_matmul`` x2, the
+    ``goma_combine`` kernel, consumer ``goma_matmul``) under the fused
     plan's compatibility tiles — the bit-identity oracle the fused
-    kernel must match token-for-token (and the execution path when a
-    chain's residency is infeasible but a fused plan exists)."""
+    kernel must match token-for-token."""
     M, K = a.shape
     _, N2 = wd.shape
     pm, pff, pk, pn2 = plan.padded
-    itp = (not _on_tpu()) if interpret is None else interpret
+    itp = interpret_default() if interpret is None else interpret
     a_p = _pad2(a, pm, pk)
     hg = goma_matmul(a_p, _pad2(wg, pk, pff), plan.producer_plan(),
                      interpret=itp)
     hu = goma_matmul(a_p, _pad2(wu, pk, pff), plan.producer_plan(),
                      interpret=itp)
-    act = ACTIVATIONS[activation](hg, hu)
+    act = goma_combine(hg, hu, plan, activation=activation, interpret=itp)
     out = goma_matmul(act, _pad2(wd, pff, pn2), plan.consumer_plan(),
                       interpret=itp)
     return out[:M, :N2]
@@ -151,7 +154,7 @@ def _fused_mlp_jit(a: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
                               dtype_bytes=jnp.dtype(a.dtype).itemsize)
     assert (plan.M, plan.FF, plan.K, plan.N2) == (M, FF, K, N2), (
         plan, (M, FF, K, N2))
-    itp = (not _on_tpu()) if interpret is None else interpret
+    itp = interpret_default() if interpret is None else interpret
     if not plan.fused:
         act = ACTIVATIONS[activation](gemm(a, wg, interpret=interpret),
                                       gemm(a, wu, interpret=interpret))

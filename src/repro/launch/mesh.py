@@ -10,6 +10,7 @@ import os
 import re
 
 import jax
+from jax.sharding import AxisType
 
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
@@ -28,7 +29,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1):
@@ -70,4 +72,5 @@ def make_host_mesh(*, data: int | None = None, model: int = 1):
             f"devices but only {n} are visible; set XLA_FLAGS="
             f"{_FORCE_FLAG}={need} before the first jax import "
             f"(see launch/dryrun.py lines 1-2)")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

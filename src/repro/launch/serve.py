@@ -18,8 +18,12 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import Engine, ServeConfig
+
+# prefill chunk buckets of the continuous deployment
+CHUNK_WIDTHS = (8, 32)
 
 
 def main() -> None:
@@ -97,6 +101,7 @@ def main() -> None:
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="seed of the fault-injection RNG streams")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.inject:
         from repro.faults import FaultInjector, parse_faults, set_injector
@@ -152,19 +157,31 @@ def main() -> None:
     print(out[:, :12])
 
 
+def continuous_engine(model, params, store, *, prompt_len: int,
+                      new_tokens: int, temperature: float = 0.0) -> Engine:
+    """The engine of a continuous deployment whose prompts are at most
+    ``prompt_len`` tokens, its cache sized for the bucketed prefill."""
+    from repro.serving.sched import BucketSpec
+    # every prompt is <= prompt_len; its bucket-padded prefill fits in
+    # ceil(prompt_len / max_width) full-width chunks
+    wmax = BucketSpec(CHUNK_WIDTHS).max_width
+    padded_cap = -(-prompt_len // wmax) * wmax
+    return Engine(model, params, ServeConfig(
+        max_new_tokens=new_tokens, temperature=temperature,
+        cache_len=padded_cap + new_tokens), plan_store=store)
+
+
 def _serve_continuous(args, cfg, model, params, store) -> None:
     from repro.serving.sched import (BucketSpec, ContinuousScheduler,
                                      SchedConfig, TraceClock,
                                      TrafficConfig, poisson_trace, replay)
-    widths = (8, 32)
-    # every trace prompt is <= prompt_len; its bucket-padded prefill
-    # fits in ceil(prompt_len / max_width) full-width chunks
+    widths = CHUNK_WIDTHS
     wmax = BucketSpec(widths).max_width
-    padded_cap = -(-args.prompt_len // wmax) * wmax
-    cache_len = padded_cap + args.new_tokens
-    eng = Engine(model, params, ServeConfig(
-        max_new_tokens=args.new_tokens, temperature=args.temperature,
-        cache_len=cache_len), plan_store=store)
+    eng = continuous_engine(model, params, store,
+                            prompt_len=args.prompt_len,
+                            new_tokens=args.new_tokens,
+                            temperature=args.temperature)
+    cache_len = eng.cfg.cache_len
     trace = poisson_trace(TrafficConfig(
         n_requests=args.requests, arrival_rate=args.arrival_rate,
         prompt_mix=((max(args.prompt_len // 4, 1), args.prompt_len, 1.0),),

@@ -18,6 +18,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import ARCHS, get_config
 from repro.data import DataConfig, global_arrays
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build_model
 from repro.sharding import data_shardings, param_shardings
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--production-mesh", action="store_true",
                     help="use the 16x16 production mesh (needs 256 devices)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family == "encdec" or cfg.family == "vlm":

@@ -155,15 +155,15 @@ def rwkv_time_apply(p: Params, cfg, x: jnp.ndarray, *,
         new_state = state * w[..., None] + kv
         y = y[:, None]
     elif getattr(cfg, "use_pallas_scan", False) and state is None:
-        # Pallas kernel path (TPU-compiled; interpret elsewhere)
-        import jax as _jax
+        # Pallas kernel path (compiled; interpreted on the CPU)
+        from ..kernels.ops import interpret_default
         from ..kernels.wkv6 import wkv6_pallas
-        C = min(cfg.ssd_chunk, S)
+        C = min(cfg.ssd_chunk, -(-S // 8) * 8)      # a multiple of 8
         pad = (-S) % C
         zpad = lambda t: jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
         y, new_state = wkv6_pallas(
             zpad(r), zpad(k), zpad(v), zpad(logw), p["u"].astype(jnp.float32),
-            chunk=C, interpret=_jax.default_backend() != "tpu")
+            chunk=C, interpret=interpret_default())
         y = y[:, :S]
     else:
         y, new_state = wkv_chunked(r, k, v, logw, p["u"],

@@ -244,7 +244,7 @@ def replay_manifest(manifest, *, dtype="float32", repeats: int = 5,
     """Replay a manifest's plans through the real Pallas kernels.
 
     ``interpret=None`` follows the kernels' own backend default
-    (interpret mode off-TPU); pass ``True`` to force the interpreter
+    (interpret mode on the CPU); pass ``True`` to force the interpreter
     path (the CI smoke gate).  ``max_entries`` caps the replay in
     manifest order.  ``progress`` is an optional ``callable(i, n,
     row)`` hook (CLI/bench reporting).

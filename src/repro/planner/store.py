@@ -87,6 +87,11 @@ def _hw_identity(hw: AcceleratorSpec) -> dict:
     d.pop("name")
     d["fixed_spatial"] = (list(hw.fixed_spatial)
                          if hw.fixed_spatial is not None else None)
+    # an unset alignment adds nothing: specs without one keep their keys
+    if hw.l1_align is None:
+        d.pop("l1_align")
+    else:
+        d["l1_align"] = list(hw.l1_align)
     return d
 
 
@@ -299,8 +304,9 @@ def spec_to_json(hw: AcceleratorSpec) -> dict:
 def spec_from_json(d: dict) -> AcceleratorSpec:
     d = dict(d)
     d["ert"] = Ert(**d["ert"])
-    if d.get("fixed_spatial") is not None:
-        d["fixed_spatial"] = tuple(d["fixed_spatial"])
+    for f in ("fixed_spatial", "l1_align"):
+        if d.get(f) is not None:
+            d[f] = tuple(d[f])
     return AcceleratorSpec(**d)
 
 

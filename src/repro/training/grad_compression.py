@@ -14,7 +14,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -48,7 +47,7 @@ def make_compressed_allreduce(mesh: Mesh, axis_names=("data",)):
     axis_names = tuple(a for a in axis_names if a in mesh.axis_names)
 
     def one(g, e):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda gg, ee: compressed_psum(gg, ee, axis_names),
             mesh=mesh,
             in_specs=(P(axis_names), P(axis_names)),

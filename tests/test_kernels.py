@@ -6,9 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.tpu_mapping import (MXU, FusedTilePlan, TpuTilePlan,
-                                    plan_fused_mlp, plan_gemm_tiling,
-                                    tpu_spec)
+from repro.core.tpu_mapping import (MXU, VMEM_BUDGET_BYTES, FusedTilePlan,
+                                    TpuTilePlan, plan_fused_mlp,
+                                    plan_gemm_tiling, tpu_spec)
 from repro.kernels.goma_gemm import goma_matmul
 from repro.kernels.ops import fused_mlp, fused_mlp_composition, gemm
 from repro.kernels.ref import matmul_ref, ssd_ref, wkv6_ref
@@ -156,23 +156,42 @@ def test_fused_mlp_activations(activation):
 
 
 def test_plan_respects_hardware_constraints():
-    hw = tpu_spec(2)
-    for (M, N, K) in [(4096, 4096, 4096), (8192, 1024, 8192),
-                      (128, 256000, 4608), (300, 200, 100)]:
-        plan = plan_gemm_tiling(M, N, K, dtype_bytes=2)
-        bm, bn, bk = plan.block
-        pm, pn, pk = plan.padded
-        assert pm % MXU == 0 and pn % MXU == 0
-        assert pm % bm == 0 and pn % bn == 0 and pk % bk == 0
-        # VMEM capacity (the GOMA SRAM constraint, words = bytes/2)
-        assert bm * bk + bk * bn + bm * bn <= hw.sram_words
-        # MXU alignment of the VMEM tile
-        assert bm % MXU == 0 and bn % MXU == 0
-        # realizability: z-walk or full reduction per block
-        assert plan.walk == "z" or bk == pk
-        # grid order puts the walking axis innermost
-        assert plan.grid_order[-1] == {"x": "m", "y": "n",
-                                       "z": "k"}[plan.walk]
+    for dtype_bytes in (2, 4):
+        hw = tpu_spec(dtype_bytes)
+        for (M, N, K) in [(4096, 4096, 4096), (8192, 1024, 8192),
+                          (128, 256000, 4608), (300, 200, 100),
+                          (256, 2048, 5632), (8, 100352, 2048)]:
+            plan = plan_gemm_tiling(M, N, K, dtype_bytes=dtype_bytes)
+            bm, bn, bk = plan.block
+            pm, pn, pk = plan.padded
+            assert pm % MXU == 0 and pn % MXU == 0
+            assert pm % bm == 0 and pn % bn == 0 and pk % bk == 0
+            # VMEM capacity (the GOMA SRAM constraint, in dtype words)
+            assert bm * bk + bk * bn + bm * bn <= hw.sram_words
+            # ...which bounds what the kernel allocates, double buffers
+            # and accumulator included
+            assert plan.vmem_bytes(dtype_bytes) <= VMEM_BUDGET_BYTES
+            # MXU alignment of the VMEM tile; K tiles too, unless whole
+            assert bm % MXU == 0 and bn % MXU == 0
+            assert bk % MXU == 0 or bk == pk
+            # realizability: z-walk or full reduction per block
+            assert plan.walk == "z" or bk == pk
+            # grid order puts the walking axis innermost
+            assert plan.grid_order[-1] == {"x": "m", "y": "n",
+                                           "z": "k"}[plan.walk]
+
+
+def test_fused_plan_counts_the_resident_down_projection():
+    """The fused kernel holds Wd's whole (FF, N2) block in VMEM: a chain
+    whose Wd exceeds the budget is planned unfused, whatever the chain
+    solver's strips allow; one that fits stays fused within budget."""
+    big = plan_fused_mlp(256, 5632, 2048, 2048, dtype_bytes=2)
+    assert 2 * 5632 * 2048 * 2 > VMEM_BUDGET_BYTES
+    assert not big.fused and big.objective == big.unfused_objective
+    small = plan_fused_mlp(256, 1024, 512, 512, dtype_bytes=2)
+    assert small.fused
+    assert small.vmem_bytes(2) <= VMEM_BUDGET_BYTES
+    assert small.bk % MXU == 0 or small.bk == small.padded[2]
 
 
 def test_plan_grid_covers_problem():
