@@ -14,13 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tpu_mapping import plan_fused_mlp, plan_gemm_tiling
-from ..obs.registry import get_registry
-from ..obs.tracing import span as _span
 from .goma_fused import ACTIVATIONS, goma_combine, goma_fused_matmul
 from .goma_gemm import goma_matmul
 from .ref import matmul_ref
-
-_REG = get_registry()
 
 
 def interpret_default() -> bool:
@@ -29,6 +25,9 @@ def interpret_default() -> bool:
     return jax.default_backend() == "cpu"
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "force_xla", "plan"))
+@jax.named_scope("goma_gemm")
 def gemm(a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool | None = None,
          force_xla: bool = False, plan=None) -> jnp.ndarray:
     """C[M,N] = A[M,K] @ B[K,N] through the GOMA-planned Pallas kernel.
@@ -38,25 +37,9 @@ def gemm(a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool | None = None,
     the in-process planner entirely.  Default: ``plan_gemm_tiling``,
     which itself reads through the plan database when one is installed.
 
-    Dispatch observability: the Python-level entry counts one
-    ``kernel.gemm.dispatch`` and, under a tracer, opens a
-    ``kernel.gemm`` span.  When this call happens inside an outer
-    ``jax.jit`` trace (the serving models), the span fires at trace
-    time — steady-state compiled execution never re-enters Python, so
-    the instrumentation costs nothing per decode tick.
+    Its ops carry the ``goma_gemm`` scope in their HLO ``op_name``, so a
+    profile finds the kernel's device time under that name.
     """
-    _REG.inc("kernel.gemm.dispatch")
-    with _span("kernel.gemm", m=int(a.shape[0]), n=int(b.shape[1]),
-               k=int(a.shape[1]), force_xla=force_xla):
-        return _gemm_jit(a, b, interpret=interpret, force_xla=force_xla,
-                         plan=plan)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("interpret", "force_xla", "plan"))
-def _gemm_jit(a: jnp.ndarray, b: jnp.ndarray, *,
-              interpret: bool | None = None,
-              force_xla: bool = False, plan=None) -> jnp.ndarray:
     if force_xla:
         return matmul_ref(a, b)
     M, K = a.shape
@@ -108,6 +91,9 @@ def fused_mlp_composition(a: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
     return out[:M, :N2]
 
 
+@functools.partial(jax.jit, static_argnames=("activation", "interpret",
+                                             "force_xla", "plan"))
+@jax.named_scope("goma_fused_mlp")
 def fused_mlp(a: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
               wd: jnp.ndarray, *, activation: str = "silu_mul",
               interpret: bool | None = None, force_xla: bool = False,
@@ -122,25 +108,9 @@ def fused_mlp(a: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
     chain solver kept the unfused pair (residency infeasible),
     dispatches the ordinary per-GEMM ``gemm`` composition instead.
 
-    Counted as ``kernel.fused_mlp.dispatch`` with a ``kernel.fused_mlp``
-    span at the Python dispatch level (trace time under an outer jit —
-    see ``gemm``).
+    Its ops carry the ``goma_fused_mlp`` scope in their HLO ``op_name``
+    (see ``gemm``).
     """
-    _REG.inc("kernel.fused_mlp.dispatch")
-    with _span("kernel.fused_mlp", m=int(a.shape[0]),
-               ff=int(wg.shape[1]), k=int(a.shape[1]),
-               n2=int(wd.shape[1]), force_xla=force_xla):
-        return _fused_mlp_jit(a, wg, wu, wd, activation=activation,
-                              interpret=interpret, force_xla=force_xla,
-                              plan=plan)
-
-
-@functools.partial(jax.jit, static_argnames=("activation", "interpret",
-                                             "force_xla", "plan"))
-def _fused_mlp_jit(a: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
-                   wd: jnp.ndarray, *, activation: str = "silu_mul",
-                   interpret: bool | None = None, force_xla: bool = False,
-                   plan=None) -> jnp.ndarray:
     M, K = a.shape
     K2, FF = wg.shape
     FF2, N2 = wd.shape

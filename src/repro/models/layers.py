@@ -184,6 +184,7 @@ def attention_init(key, d_model: int, n_heads: int, kv_heads: int,
     }
 
 
+@jax.named_scope("attention")
 def attention_apply(p: Params, x: jnp.ndarray, *,
                     n_heads: int, kv_heads: int, head_dim: int,
                     rope_theta: float | None,
@@ -276,6 +277,7 @@ def mlp_init(key, d_model: int, d_ff: int, dtype) -> Params:
             "wd": dense_init(ks[2], d_ff, d_model, dtype)}
 
 
+@jax.named_scope("mlp")
 def mlp_apply(p: Params, x: jnp.ndarray, activation: str = "silu",
               use_fused: bool = False) -> jnp.ndarray:
     if use_fused:

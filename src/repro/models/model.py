@@ -133,6 +133,7 @@ class Model:
         return p
 
     # ---------------------------------------------------------------- embed
+    @jax.named_scope("embed")
     def _embed_in(self, params, tokens, prefix: jnp.ndarray | None):
         cfg = self.cfg
         x = L.embed(params["embed"], tokens)
@@ -142,6 +143,7 @@ class Model:
             x = jnp.concatenate([prefix.astype(x.dtype), x], axis=1)
         return x.astype(L.dtype_of(cfg.compute_dtype))
 
+    @jax.named_scope("lm_head")
     def _lm_logits(self, params, x):
         cfg = self.cfg
         x = L.apply_norm(params["final_norm"], x, cfg.norm)

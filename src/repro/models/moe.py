@@ -38,6 +38,7 @@ def moe_init(key, cfg, dtype) -> Params:
     return p
 
 
+@jax.named_scope("moe")
 def moe_apply(p: Params, cfg, x: jnp.ndarray):
     """x: (B,S,d).  Returns (y, aux_loss); dispatch per cfg.moe_dispatch.
 

@@ -26,8 +26,11 @@ Conventions used across the repo:
   plan_store.{hits,misses,puts}   content-addressed store traffic
   planner.batches                 ``BatchPlanner.plan_gemms`` builds
   capture.{traces,plans}          jaxpr capture / program planning
-  kernel.{gemm,fused_mlp}.dispatch   Python-level kernel dispatches
-                                     (trace-time under jit)
+  jit.compiles                    backend compiles of the process
+                                  (``obs.tracing.count_compiles``,
+                                  installed by ``Engine``; under a
+                                  tracer each is a ``jit.compile``
+                                  event with its ``fun_name``)
   sched.*                         scheduler ticks / chunks / tokens
   sched.spec.{rounds,drafted,accepted}   scheduler-side speculative
                                   verify rounds and acceptance tallies
@@ -62,6 +65,21 @@ Resilience namespaces (see ``repro.faults`` and DESIGN.md §Resilience):
                                   degraded.plans.bounded_served
   sched.prewarm_failures          per-group/per-shape prewarm failures
                                   that were logged and skipped
+
+Spans (``obs.tracing``) of one scheduler tick, each with ``tick=``:
+
+  sched.tick                      the tick (a profiler step annotation)
+    sched.admit                   admission: queue pop, slot, padded
+                                  prompt buffer, prefix lookup
+    sched.prefill_chunk           one chunk (``width``/``start``/``real``)
+    sched.graft                   first-token sample, ``insert_row``,
+                                  prefix insert
+    sched.decode_batch            one decode step over the slot pool
+      sched.decode.dispatch       inputs, ``decode_slots``, last logits
+      sched.decode.guard          ``_guard_rows``' finiteness read
+      sched.decode.sample         ``_sample_rows``' sampling read
+    sched.emit                    streaming the step's tokens
+  sched.request                   detached, admit to finish (``req_id``)
 """
 from __future__ import annotations
 

@@ -10,9 +10,11 @@ Beyond the static ``generate`` loop, the engine exposes its *step-level*
 primitives — ``new_cache`` / ``prefill_chunk`` / ``decode_slots`` /
 ``insert_row`` / ``sample`` — which the continuous-batching scheduler
 (``serving.sched``) composes into an admission/prefill/decode loop.  All
-of them route through the single jitted ``model.decode_step``, so the
-number of distinct compiled programs is bounded by the number of chunk
-widths in use (see sched.BucketSpec), not by traffic.
+of them run ``model.decode_step``, so the number of distinct compiled
+programs is bounded by the number of chunk widths in use (see
+sched.BucketSpec), not by traffic.  Each primitive jits it under its own
+name (``jit_prefill_chunk``, ``jit_decode_slots``, ``jit_insert_row``,
+``jit_verify_step``), so a profile tells their device time apart.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ import numpy as np
 
 from ..models.model import Model
 from ..obs.registry import get_registry
+from ..obs.tracing import count_compiles
 
 _REG = get_registry()
 _LOG = logging.getLogger(__name__)
@@ -47,7 +50,16 @@ def gumbel_argmax(logits, temperature: float, key):
     return jnp.argmax(logits / temperature + g, axis=-1).astype(jnp.int32)
 
 
-def _insert_row(slot_cache, row_cache, slot):
+def _named_jit(fn, name: str):
+    """``jax.jit(fn)`` whose program is named ``jit_<name>``: the same
+    computation, told apart from other jits of ``fn`` in a profile."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named)
+
+
+def insert_row(slot_cache, row_cache, slot):
     """Write a freshly prefilled B=1 cache row into slot `slot` of the
     slot-batched cache (batch is axis 1 of every KV leaf)."""
     return jax.tree.map(
@@ -65,18 +77,22 @@ class Engine:
         # optional GOMA plan database (repro.planner.PlanStore): serving
         # traffic consumes cached kernel tilings instead of solving inline
         self.plan_store = plan_store
+        count_compiles()
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, max_len=cfg.cache_len))
         # chunk-capable, slot-indexable (see model.decode_step); one
-        # compiled program per distinct (B, S) / index-rank signature
-        self._decode = jax.jit(model.decode_step)
-        self._insert = jax.jit(_insert_row)
+        # compiled program per distinct (B, S) / index-rank signature,
+        # jitted under the name of the primitive that runs it
+        self._decode = jax.jit(model.decode_step)       # generate()
+        self._prefill_chunk = _named_jit(model.decode_step, "prefill_chunk")
+        self._decode_slots = _named_jit(model.decode_step, "decode_slots")
+        self._insert = jax.jit(insert_row)
 
         # speculative-decoding verify: one decode_step over a whole
         # draft window, greedy-argmaxed *inside* the jit so only (B, W)
         # token ids and a (B,) finiteness mask cross to the host — never
         # the (B, W, V) logits (the verify loop is per-token otherwise)
-        def _verify(p, c, t, i):
+        def verify_step(p, c, t, i):
             logits, cache = model.decode_step(p, c, t, i)
             greedy = jnp.argmax(
                 jnp.where(jnp.isfinite(logits), logits, -jnp.inf),
@@ -84,7 +100,7 @@ class Engine:
             finite = jnp.all(jnp.isfinite(logits), axis=(1, 2))
             return greedy, finite, cache
 
-        self._verify = jax.jit(_verify)
+        self._verify = jax.jit(verify_step)
 
     # ----------------------------------------------------- step-level API
     def new_cache(self, batch: int):
@@ -94,15 +110,15 @@ class Engine:
     def prefill_chunk(self, cache, tokens, index):
         """Run one prefill chunk (B, W) at scalar write position `index`
         against an existing cache; returns (logits (B, W, V), cache)."""
-        return self._decode(self.params, cache, jnp.asarray(tokens),
-                            jnp.asarray(index, jnp.int32))
+        return self._prefill_chunk(self.params, cache, jnp.asarray(tokens),
+                                   jnp.asarray(index, jnp.int32))
 
     def decode_slots(self, cache, tokens, positions):
         """One decode step with per-row write positions (B,); rows are
         fully independent — inactive slots may carry garbage, their
         writes land below/at their own positions only."""
-        return self._decode(self.params, cache, jnp.asarray(tokens),
-                            jnp.asarray(positions, jnp.int32))
+        return self._decode_slots(self.params, cache, jnp.asarray(tokens),
+                                  jnp.asarray(positions, jnp.int32))
 
     def verify_step(self, cache, tokens, positions):
         """One speculative-verify step: decode ``tokens`` (B, W) — per

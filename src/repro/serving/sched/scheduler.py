@@ -483,14 +483,18 @@ class ContinuousScheduler:
     def step(self) -> None:
         """One scheduler tick: admit -> prefill chunk(s) -> decode.
 
-        Observability: the tick is one ``sched.tick`` span with
-        ``sched.prefill_chunk`` / ``sched.decode_batch`` children;
-        admission opens a detached per-request ``sched.request`` span
-        that ``_emit`` closes at finish.  Registry counters mirror the
+        Observability: the tick is one ``sched.tick`` span (a step on
+        the profiler's clock) with children ``sched.admit``,
+        ``sched.prefill_chunk``, ``sched.graft``, ``sched.decode_batch``
+        (itself split into ``sched.decode.dispatch`` / ``.guard`` /
+        ``.sample``) and ``sched.emit``, each with ``tick=``; admission
+        opens a detached per-request ``sched.request`` span that
+        ``_emit`` closes at finish.  Registry counters mirror the
         ``ServingMetrics`` tick accounting under ``sched.*``."""
         wall0 = time.perf_counter()
-        with _span("sched.tick", tick=self.metrics.steps) as tick_sp:
-            self._step_inner(tick_sp)
+        tick = self.metrics.steps
+        with _span("sched.tick", step_num=tick, tick=tick) as tick_sp:
+            self._step_inner(tick_sp, tick)
         wall = time.perf_counter() - wall0
         if self.cfg.watchdog_tick_s is not None and \
                 wall > self.cfg.watchdog_tick_s:
@@ -503,7 +507,7 @@ class ContinuousScheduler:
         if self.on_tick is not None:
             self.on_tick(self)
 
-    def _step_inner(self, tick_sp) -> None:
+    def _step_inner(self, tick_sp, tick: int) -> None:
         if not self.metrics.steps:
             self.metrics.started_s = self.clock()
         chunks_run = 0
@@ -520,41 +524,8 @@ class ContinuousScheduler:
 
         # 1. admission: start prefilling the oldest queued request
         if self._prefill is None and self.queue and self.slots.n_free:
-            req = self.queue.popleft()
-            slot = self.slots.acquire(req)
-            if slot.stop_token is None:     # scheduler default, resolved
-                slot.stop_token = self.cfg.stop_token   # on the slot —
-            #                                 the Request is never mutated
-            padded_len = self.buckets.padded_len(req.prompt_len)
-            buf = np.zeros((1, padded_len), np.int32)
-            buf[0, :req.prompt_len] = req.tokens
-            chunks = self.buckets.plan_chunks(req.prompt_len)
-            if self.prefix_cache is not None:
-                # KV prefix reuse: a cached prefix of P tokens (always a
-                # full-chunk boundary, always < prompt_len) is grafted
-                # into the prefill cache and its chunks are skipped —
-                # the remaining chunks read the grafted rows through
-                # attention exactly as if they had just been prefilled
-                # (KV at position i depends only on tokens <= i)
-                hit = self.prefix_cache.lookup(req.tokens)
-                if hit is not None:
-                    p, entry = hit
-                    self._prefill_cache = self.prefix_cache.graft(
-                        self._prefill_cache, entry)
-                    chunks = [c for c in chunks
-                              if c.start + c.width > p]
-                    _REG.inc("sched.prefix_tokens_reused", p)
-            self._prefill = _Prefill(
-                slot=slot, cache=self._prefill_cache,
-                chunks=collections.deque(chunks),
-                padded=buf)
-            _REG.inc("sched.admitted")
-            tr = get_tracer()
-            if tr is not None:
-                self._req_spans[req.req_id] = tr.start(
-                    "sched.request", detached=True, req_id=req.req_id,
-                    prompt_len=req.prompt_len,
-                    max_new_tokens=req.max_new_tokens)
+            with _span("sched.admit", tick=tick):
+                self._admit()
 
         # 2. chunked prefill of the in-flight request
         budget = max(1, self.cfg.prefill_chunks_per_step)
@@ -563,7 +534,7 @@ class ContinuousScheduler:
             toks = self._prefill.padded[:, chunk.start:chunk.start
                                         + chunk.width]
             with _span("sched.prefill_chunk", width=chunk.width,
-                       start=chunk.start, real=chunk.n_real):
+                       start=chunk.start, real=chunk.n_real, tick=tick):
                 logits, self._prefill.cache = self.engine.prefill_chunk(
                     self._prefill.cache, toks, chunk.start)
             chunks_run += 1
@@ -573,7 +544,8 @@ class ContinuousScheduler:
                      chunk.width - chunk.n_real)
             budget -= 1
             if not self._prefill.chunks:
-                self._activate(self._prefill, logits, chunk)
+                with _span("sched.graft", tick=tick):
+                    self._activate(self._prefill, logits, chunk)
                 self._prefill = None
             self._resolve_plans(f"chunk{chunk.width}")
 
@@ -587,26 +559,33 @@ class ContinuousScheduler:
         elif active:
             decoded = True
             with _span("sched.decode_batch", rows=len(active),
-                       slots=len(self.slots)):
-                tokens = jnp.asarray(self._cur[:, None])
-                positions = jnp.asarray(self._pos)
-                logits, self.slot_cache = self.engine.decode_slots(
-                    self.slot_cache, tokens, positions)
-                last = logits[:, -1]
-                hit = inject("kernel.nan_row")
-                if hit is not None:     # chaos: poison one active row's
-                    victim = active[hit.index % len(active)].idx
-                    bad = float(hit.payload.get("value", float("nan")))
-                    last = last.at[victim].set(bad)      # logits in-place
-                active = self._guard_rows(last, active)
-                nxt = self._sample_rows(last, active) if active else None
+                       slots=len(self.slots), tick=tick):
+                with _span("sched.decode.dispatch", tick=tick):
+                    tokens = jnp.asarray(self._cur[:, None])
+                    positions = jnp.asarray(self._pos)
+                    logits, self.slot_cache = self.engine.decode_slots(
+                        self.slot_cache, tokens, positions)
+                    last = logits[:, -1]
+                    hit = inject("kernel.nan_row")
+                    if hit is not None:     # chaos: poison one active
+                        #                         row's logits in place
+                        victim = active[hit.index % len(active)].idx
+                        bad = float(hit.payload.get("value",
+                                                    float("nan")))
+                        last = last.at[victim].set(bad)
+                with _span("sched.decode.guard", tick=tick):
+                    active = self._guard_rows(last, active)
+                with _span("sched.decode.sample", tick=tick):
+                    nxt = (self._sample_rows(last, active) if active
+                           else None)
             now = self.clock()
-            for slot in active:
-                tok = int(nxt[slot.idx])
-                self._pos[slot.idx] += 1
-                self._cur[slot.idx] = tok
-                slot.next_token = tok
-                self._emit(slot, tok, now)
+            with _span("sched.emit", tick=tick):
+                for slot in active:
+                    tok = int(nxt[slot.idx])
+                    self._pos[slot.idx] += 1
+                    self._cur[slot.idx] = tok
+                    slot.next_token = tok
+                    self._emit(slot, tok, now)
             _REG.inc("sched.decode_steps")
             _REG.inc("sched.padded_decode_rows",
                      len(self.slots) - len(active))
@@ -621,6 +600,46 @@ class ContinuousScheduler:
             chunks=chunks_run, padded_tokens=padded_tokens,
             padded_rows=padded_rows)
         self.metrics.finished_s = self.clock()
+
+    def _admit(self) -> None:
+        """Start prefilling the oldest queued request in a free slot:
+        its padded prompt buffer, its chunk plan, and any cached prefix
+        grafted into the prefill cache."""
+        req = self.queue.popleft()
+        slot = self.slots.acquire(req)
+        if slot.stop_token is None:     # scheduler default, resolved
+            slot.stop_token = self.cfg.stop_token   # on the slot —
+        #                                 the Request is never mutated
+        padded_len = self.buckets.padded_len(req.prompt_len)
+        buf = np.zeros((1, padded_len), np.int32)
+        buf[0, :req.prompt_len] = req.tokens
+        chunks = self.buckets.plan_chunks(req.prompt_len)
+        if self.prefix_cache is not None:
+            # KV prefix reuse: a cached prefix of P tokens (always a
+            # full-chunk boundary, always < prompt_len) is grafted
+            # into the prefill cache and its chunks are skipped —
+            # the remaining chunks read the grafted rows through
+            # attention exactly as if they had just been prefilled
+            # (KV at position i depends only on tokens <= i)
+            hit = self.prefix_cache.lookup(req.tokens)
+            if hit is not None:
+                p, entry = hit
+                self._prefill_cache = self.prefix_cache.graft(
+                    self._prefill_cache, entry)
+                chunks = [c for c in chunks
+                          if c.start + c.width > p]
+                _REG.inc("sched.prefix_tokens_reused", p)
+        self._prefill = _Prefill(
+            slot=slot, cache=self._prefill_cache,
+            chunks=collections.deque(chunks),
+            padded=buf)
+        _REG.inc("sched.admitted")
+        tr = get_tracer()
+        if tr is not None:
+            self._req_spans[req.req_id] = tr.start(
+                "sched.request", detached=True, req_id=req.req_id,
+                prompt_len=req.prompt_len,
+                max_new_tokens=req.max_new_tokens)
 
     # ------------------------------------------------- speculative decode
     def _decode_spec(self, active: list[Slot]) -> list[Slot]:

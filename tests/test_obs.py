@@ -9,6 +9,7 @@ NaN-safe metrics summary, and a small fidelity replay.
 from __future__ import annotations
 
 import json
+import re
 
 import numpy as np
 import pytest
@@ -105,6 +106,65 @@ class TestTracer:
         assert [s.name for s in t2.spans] == ["x"]
         assert t1.spans == []
         set_tracer(None)
+
+    def test_profiler_annotations_follow_stacked_spans(self, monkeypatch):
+        """Stacked spans enter a profiler annotation of their name and
+        opening attributes (a step annotation with ``step_num``) and
+        leave it when they end; detached spans and events enter none,
+        and with no tracer installed, or no profile recording, none is
+        even built."""
+        jax = pytest.importorskip("jax")
+        log = []
+        recording = [False]
+
+        class Ann:
+            step = False
+
+            @staticmethod
+            def is_enabled():
+                return recording[0]
+
+            def __init__(self, name, **kw):
+                log.append(("new", name, self.step, kw))
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        class StepAnn(Ann):
+            step = True
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", StepAnn)
+        recording[0] = True
+        with obs_span("sched.tick", step_num=3, tick=3):
+            with obs_span("sched.emit", tick=3):
+                pass
+        assert log == []                          # no tracer: nothing built
+        tr = Tracer()
+        set_tracer(tr)
+        recording[0] = False
+        with obs_span("sched.tick", step_num=2, tick=2):
+            pass
+        assert log == []                          # no profile: none built
+        recording[0] = True
+        with obs_span("sched.tick", step_num=3, tick=3):
+            req = tr.start("sched.request", detached=True, req_id=1)
+            with obs_span("sched.emit", tick=3):
+                trace_event("sched.first_token", req_id=1)
+        tr.end(req)
+        assert log == [
+            ("new", "sched.tick", True, {"step_num": 3, "tick": 3}),
+            ("enter", "sched.tick"),
+            ("new", "sched.emit", False, {"tick": 3}),
+            ("enter", "sched.emit"), ("exit", "sched.emit"),
+            ("exit", "sched.tick")]
+        # the host record is unchanged: step_num is not an attribute
+        assert [sp.attrs for sp in tr.by_name("sched.tick")] == [
+            {"tick": 2}, {"tick": 3}]
 
 
 # -------------------------------------------------------------- registry
@@ -220,6 +280,38 @@ class TestSchedulerSpans:
             assert rs.attrs["n_generated"] == 4
             kids = [s.name for s in tr.children(rs)]
             assert kids == ["sched.first_token"]
+            assert set(rs.attrs) >= {"req_id"}
+        # the tick's sub-spans, each under its parent, each with tick=
+        by_sid = {s.sid: s for s in tr.spans}
+        parent_of = {"sched.admit": "sched.tick",
+                     "sched.prefill_chunk": "sched.tick",
+                     "sched.graft": "sched.tick",
+                     "sched.decode_batch": "sched.tick",
+                     "sched.emit": "sched.tick",
+                     "sched.decode.dispatch": "sched.decode_batch",
+                     "sched.decode.guard": "sched.decode_batch",
+                     "sched.decode.sample": "sched.decode_batch"}
+        assert set(parent_of) <= set(names)
+        for sp in tr.spans:
+            if sp.name in parent_of:
+                up = by_sid[sp.parent]
+                assert up.name == parent_of[sp.name], sp
+                assert sp.attrs["tick"] == up.attrs["tick"]
+                assert up.t0 <= sp.t0 <= sp.t1 <= up.t1
+        assert names.count("sched.admit") == 2
+        assert names.count("sched.graft") == 2
+        for db in tr.by_name("sched.decode_batch"):
+            assert [c.name for c in tr.children(db)] == [
+                "sched.decode.dispatch", "sched.decode.guard",
+                "sched.decode.sample"]
+        # what the benchmark reads keeps its names and attributes
+        assert [s.attrs["tick"] for s in tr.by_name("sched.tick")] == \
+            list(range(sched.metrics.steps))
+        for pc in tr.by_name("sched.prefill_chunk"):
+            assert {"real", "start", "width"} <= set(pc.attrs)
+            assert by_sid[pc.parent].name == "sched.tick"
+        for db in tr.by_name("sched.decode_batch"):
+            assert by_sid[db.parent].name == "sched.tick"
         # on_tick fired once per step, after the tick span closed
         assert ticks == list(range(1, sched.metrics.steps + 1))
         reg = get_registry()
@@ -228,6 +320,128 @@ class TestSchedulerSpans:
         assert reg.get("sched.tokens") == 8
         assert reg.get("sched.padded_decode_rows") == \
             sched.metrics.padded_decode_rows
+
+
+    def test_spans_on_the_profiler_clock(self, tmp_path):
+        """A scheduler ticking under a tracer inside a profile leaves
+        ``sched.tick`` and its sub-spans in the trace's host plane, each
+        sub-span inside its tick."""
+        jax = pytest.importorskip("jax")
+        from jax.profiler import ProfileData
+
+        from repro.serving.sched import ContinuousScheduler, Request
+        from repro.serving.sched import SchedConfig
+
+        engine = _smoke_engine(jax)
+        rng = np.random.default_rng(1)
+        sched = ContinuousScheduler(
+            engine, SchedConfig(slots=2, chunk_widths=(4, 8)))
+        sched.run([Request(req_id=0, max_new_tokens=3,
+                           tokens=rng.integers(0, 64, (6,)))])  # compile
+        before = sched.metrics.steps
+        set_tracer(Tracer())
+        try:
+            with jax.profiler.trace(str(tmp_path)):
+                sched.run([Request(req_id=1, max_new_tokens=3,
+                                   tokens=rng.integers(0, 64, (6,)))])
+        finally:
+            set_tracer(None)
+        path = sorted(tmp_path.glob("**/*.xplane.pb"))[-1]
+        events = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+                  for plane in ProfileData.from_file(str(path)).planes
+                  if plane.name == "/host:CPU"
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("sched.")]
+        ticks = [e for e in events if e[0] == "sched.tick"]
+        assert len(ticks) == sched.metrics.steps - before > 0
+        assert [t[3]["tick"] for t in ticks] == \
+            [t[3]["step_num"] for t in ticks]
+        subs = {"sched.admit", "sched.prefill_chunk", "sched.graft",
+                "sched.decode_batch", "sched.decode.dispatch",
+                "sched.decode.guard", "sched.decode.sample", "sched.emit"}
+        assert subs <= {e[0] for e in events}
+        for name, t0, t1, stats in events:
+            if name in subs:
+                tick = [t for t in ticks if t[3]["tick"] == stats["tick"]]
+                assert len(tick) == 1, (name, stats)
+                assert tick[0][1] <= t0 <= t1 <= tick[0][2], name
+
+
+def _smoke_engine(jax):
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving import Engine, ServeConfig
+
+    cfg = get_config("llama3-8b", smoke=True)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    return Engine(model, params, ServeConfig(max_new_tokens=4,
+                                             cache_len=32))
+
+
+# ---------------------------------------------------------- device names
+class TestDeviceNames:
+    def test_engine_programs_and_layer_scopes(self):
+        """Prefill chunks and decode are programs of their own names, and
+        their ops carry the model's layer scopes in ``op_name``."""
+        jax = pytest.importorskip("jax")
+        import jax.numpy as jnp
+
+        engine = _smoke_engine(jax)
+        texts = {}
+        for name, fn, batch, index in (
+                ("prefill_chunk", engine._prefill_chunk, 1,
+                 jnp.int32(0)),
+                ("decode_slots", engine._decode_slots, 2,
+                 jnp.zeros((2,), jnp.int32))):
+            width = 4 if batch == 1 else 1
+            lowered = fn.lower(engine.params, engine.new_cache(batch),
+                               jnp.zeros((batch, width), jnp.int32), index)
+            texts[name] = lowered.compile().as_text()
+        for name, text in texts.items():
+            assert text.startswith(f"HloModule jit_{name}"), text[:80]
+            ops = " ".join(re.findall(r'op_name="([^"]*)"', text))
+            assert f"jit({name})/" in ops
+            for scope in ("/attention/", "/mlp/", "/lm_head/", "/embed/"):
+                assert scope in ops, (name, scope)
+
+    def test_kernel_scopes(self):
+        jax = pytest.importorskip("jax")
+        import jax.numpy as jnp
+
+        from repro.kernels.ops import fused_mlp, gemm
+
+        a = jnp.ones((8, 16), jnp.float32)
+        w = jnp.ones((16, 32), jnp.float32)
+        wd = jnp.ones((32, 16), jnp.float32)
+        text = jax.jit(lambda a, w, wd: fused_mlp(
+            gemm(a, w, force_xla=True) @ wd, w, w, wd,
+            force_xla=True)).lower(a, w, wd).compile().as_text()
+        assert "/goma_gemm/" in text and "/goma_fused_mlp/" in text
+
+    def test_compile_counter(self):
+        """Each backend compile counts in ``jit.compiles`` and, under a
+        tracer, leaves a ``jit.compile`` event naming the program."""
+        jax = pytest.importorskip("jax")
+
+        from repro.obs.tracing import count_compiles
+
+        count_compiles()
+        count_compiles()                 # one listener, however called
+
+        def compile_counter_probe(x):
+            return x * 3 + 1
+
+        tr = Tracer()
+        set_tracer(tr)
+        try:
+            jax.jit(compile_counter_probe)(np.ones((7, 5), np.float32))
+        finally:
+            set_tracer(None)
+        assert get_registry().get("jit.compiles") == 1
+        (ev,) = tr.by_name("jit.compile")
+        assert "compile_counter_probe" in ev.attrs["fun_name"]
+        assert ev.attrs["duration_s"] > 0 and ev.t0 == ev.t1
 
 
 # --------------------------------------------------------------- metrics
