@@ -51,3 +51,28 @@ def ssd_ref(xh, dt, a_log, Bm, Cm, D):
     _, ys = jax.lax.scan(step, s0, xs)
     ys = ys.swapaxes(0, 1)
     return ys + xh * D[None, None, :, None]
+
+
+def decode_attention_ref(q, k, v, *, kv_len, q_positions, window=None,
+                         window_active=True, softcap=None):
+    """Plain float32 softmax oracle for one query token per row;
+    q: (B,1,H,hd), k/v: (B,T,KV,hd), kv_len/q_positions: (B,)."""
+    B, _, H, hd = q.shape
+    T, KV = k.shape[1], k.shape[2]
+    G = H // KV
+    kf = jnp.repeat(k.astype(jnp.float32), G, axis=2)      # (B,T,H,hd)
+    vf = jnp.repeat(v.astype(jnp.float32), G, axis=2)
+    s = jnp.einsum("bhd,bthd->bht", q[:, 0].astype(jnp.float32), kf,
+                   precision="highest") / jnp.sqrt(jnp.float32(hd))
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    t = jnp.arange(T)[None, :]
+    qpos = jnp.asarray(q_positions)[:, None]
+    ok = (t < jnp.asarray(kv_len)[:, None]) & (t <= qpos)
+    if window is not None:
+        ok = ok & ((qpos - t < window) | jnp.logical_not(window_active))
+    s = jnp.where(ok[:, None, :], s, -jnp.inf)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bht,bthd->bhd", p, vf, precision="highest")
+    return out[:, None]

@@ -4,7 +4,8 @@ Parameters are nested dicts of jnp arrays; every layer is an (init, apply)
 pair of pure functions.  Attention is flash-style (KV-block scan with an
 online softmax) so 32k-prefill and 500k-decode activations never
 materialize the full score matrix — required for the dry-run memory
-budgets (DESIGN.md §5).
+budgets (DESIGN.md §5).  A single-token step over a cache on one device
+runs the Pallas decode kernel (``kernels/decode_attention.py``) instead.
 """
 from __future__ import annotations
 
@@ -13,6 +14,10 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+
+from ..kernels.decode_attention import (decode_attention, stored_t_minor,
+                                        write_rows)
+from ..kernels.ops import interpret_default
 
 Params = dict
 
@@ -173,6 +178,13 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 # GQA attention layer (self or cross), with optional KV cache
 # ---------------------------------------------------------------------------
 
+def _on_one_device(*xs) -> bool:
+    """True unless an operand's type spans a mesh of several devices:
+    the partitioner cannot split a Mosaic kernel, so a sharded program
+    keeps the flash-attention scan."""
+    return all(jax.typeof(x).sharding.mesh.size <= 1 for x in xs)
+
+
 def attention_init(key, d_model: int, n_heads: int, kv_heads: int,
                    head_dim: int, dtype) -> Params:
     ks = jax.random.split(key, 4)
@@ -227,14 +239,10 @@ def attention_apply(p: Params, x: jnp.ndarray, *,
         if cache_index is not None and \
                 getattr(cache_index, "ndim", 0) == 1:
             # slot-indexed write: each batch row has its own position
-            # (continuous-batching decode, serving/sched) — per-row
-            # dynamic_update_slice via vmap, per-row valid-length mask
-            def _row(c, u, i):
-                return jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-            k_all = jax.vmap(_row)(
-                cache["k"], k.astype(cache["k"].dtype), cache_index)
-            v_all = jax.vmap(_row)(
-                cache["v"], v.astype(cache["v"].dtype), cache_index)
+            # (continuous-batching decode, serving/sched), per-row
+            # valid-length mask
+            k_all = write_rows(cache["k"], k, cache_index)
+            v_all = write_rows(cache["v"], v, cache_index)
             kv_len = cache_index + S                     # (B,)
         elif cache_index is not None:
             k_all = jax.lax.dynamic_update_slice(
@@ -256,12 +264,22 @@ def attention_apply(p: Params, x: jnp.ndarray, *,
     else:
         new_cache = None
 
-    kv_len_arr = (None if kv_len is None
-                  else jnp.asarray(kv_len, jnp.int32).reshape(-1))
-    out = flash_attention(q, k, v, q_positions=q_positions,
-                          kv_positions=kv_positions, causal=causal,
-                          window=window, window_active=window_active,
-                          kv_len=kv_len_arr, softcap=softcap, block=block)
+    if (cache is not None and not static_cache and cache_index is not None
+            and S == 1 and stored_t_minor(k.shape) and _on_one_device(q, k)):
+        # one new token per row against its cache: the decode kernel
+        # reads only each row's valid blocks, in the cache's own layout
+        out = decode_attention(
+            q, k, v, kv_len=kv_len, q_positions=q_positions, causal=causal,
+            window=window, window_active=window_active, softcap=softcap,
+            interpret=interpret_default())
+    else:
+        kv_len_arr = (None if kv_len is None
+                      else jnp.asarray(kv_len, jnp.int32).reshape(-1))
+        out = flash_attention(q, k, v, q_positions=q_positions,
+                              kv_positions=kv_positions, causal=causal,
+                              window=window, window_active=window_active,
+                              kv_len=kv_len_arr, softcap=softcap,
+                              block=block)
     out = dense(p["wo"], out.reshape(B, S, n_heads * head_dim))
     return out, new_cache
 

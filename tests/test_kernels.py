@@ -11,7 +11,10 @@ from repro.core.tpu_mapping import (MXU, VMEM_BUDGET_BYTES, FusedTilePlan,
                                     plan_gemm_tiling, tpu_spec)
 from repro.kernels.goma_gemm import goma_matmul
 from repro.kernels.ops import fused_mlp, fused_mlp_composition, gemm
-from repro.kernels.ref import matmul_ref, ssd_ref, wkv6_ref
+from repro.kernels.decode_attention import BLOCK, decode_attention
+from repro.kernels.ref import (decode_attention_ref, matmul_ref, ssd_ref,
+                               wkv6_ref)
+from repro.models.layers import flash_attention
 
 SHAPES = [(128, 128, 128), (256, 512, 128), (300, 200, 100),
           (512, 384, 1024), (1024, 256, 2048), (64, 4096, 512)]
@@ -288,3 +291,89 @@ def test_wkv6_pallas_vs_ref(chunk, dtype):
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(ref), rtol=tol, atol=tol)
+
+
+# --- decode attention: one query token per row against its cache --------
+# tk = BLOCK; lengths 1, tk-1, tk, tk+1 and T sit in one batch,
+# so the clamped block walk, the partial last block (T = 300) and the
+# per-row finish are all exercised.
+
+DECODE_CASES = {
+    "g1_T300": dict(T=300, G=1),
+    "g2_T256": dict(T=256, G=2),
+    "g4_T300": dict(T=300, G=4),
+    "g2_window": dict(T=300, G=2, window=100, window_active=True),
+    "g4_window_switched_off": dict(T=300, G=4, window=100,
+                                   window_active=False),
+    "g2_softcap": dict(T=300, G=2, softcap=20.0),
+    "g2_scalar_index": dict(T=300, G=2, scalar=True),
+    "g2_bf16": dict(T=300, G=2, dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_CASES), ids=str)
+def test_decode_attention_vs_references(case):
+    c = DECODE_CASES[case]
+    T, G, KV, hd, tk = c["T"], c["G"], 2, 64, BLOCK
+    dtype = c.get("dtype", jnp.float32)
+    lens = ([200] * 5 if c.get("scalar")
+            else [1, tk - 1, tk, tk + 1, T])
+    B = len(lens)
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = _rand(ks[0], (B, 1, KV * G, hd), dtype) * 10
+    k = _rand(ks[1], (B, T, KV, hd), dtype) * 10
+    v = _rand(ks[2], (B, T, KV, hd), dtype) * 10
+    kv_len = jnp.asarray(lens, jnp.int32)
+    window, softcap = c.get("window"), c.get("softcap")
+    active = jnp.asarray(c.get("window_active", True))
+
+    @jax.jit
+    def kernel(q, k, v, kv_len, active):
+        if c.get("scalar"):      # one shared write position, broadcast
+            kv_len = kv_len[0]
+        return decode_attention(q, k, v, kv_len=kv_len,
+                                q_positions=kv_len - 1, window=window,
+                                window_active=active, softcap=softcap,
+                                interpret=True)
+
+    out = np.asarray(kernel(q, k, v, kv_len, active), np.float32)
+    flash = flash_attention(q, k, v, q_positions=(kv_len - 1)[:, None],
+                            kv_positions=jnp.arange(T), window=window,
+                            window_active=active, kv_len=kv_len,
+                            softcap=softcap)
+    ref = decode_attention_ref(q, k, v, kv_len=kv_len,
+                               q_positions=kv_len - 1, window=window,
+                               window_active=active, softcap=softcap)
+    # f32 accumulation either way; a bf16 output rounds once
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out, np.asarray(flash, np.float32),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(out, np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("head_dim", [16, 128])
+def test_decode_kernel_runs_only_in_single_token_decode(head_dim):
+    """The dispatch reads the shapes: per-slot decode (S = 1) runs the
+    Pallas kernel, a 32-wide prefill chunk keeps the flash scan, and so
+    does a cache whose head_dim fills whole lane tiles (stored
+    row-major, so the kernel could not read it in place)."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving import Engine, ServeConfig
+    cfg = get_config("llama3-8b", smoke=True).replace(head_dim=head_dim)
+    model = build_model(cfg)
+    eng = Engine(model, model.init_params(jax.random.PRNGKey(0)),
+                 ServeConfig(cache_len=64))
+
+    def jaxpr(fn, batch, width, index):
+        return str(jax.make_jaxpr(fn)(
+            eng.new_cache(batch), jnp.zeros((batch, width), jnp.int32),
+            index))
+
+    decode = jaxpr(eng.decode_slots, 3, 1, jnp.array([0, 5, 9], jnp.int32))
+    chunk = jaxpr(eng.prefill_chunk, 1, 32, jnp.int32(0))
+    in_place = head_dim % 128 != 0
+    assert ("pallas_call" in decode) == in_place
+    assert ("decode_attention" in decode) == in_place
+    assert "pallas_call" not in chunk
