@@ -16,12 +16,19 @@ whose ``hd`` is a multiple of 128 is stored row-major; the kernel would
 need a copy of it, so ``stored_t_minor`` tells callers to keep the
 flash scan there.
 
+Every layer's cache, stacked ``(L, B, T, KV, hd)``, is read the same
+way: its view is ``(L, B, KV * hd, T)`` and the layer to read arrives by
+scalar prefetch, so a layer scan can carry the whole stacked cache and
+write each layer's new rows into it in place (``write_rows`` with a
+``layer``) with no slice of a layer taken out or stacked back.
+
 Grid ``(B, T / tk)``; a block is ``(KV * hd, tk)``: every head, ``tk``
 = 128 positions (one lane tile; fewer if the cache is shorter).
 Per-row lengths arrive by scalar prefetch, and
-the K/V ``index_map`` clamps the block index to the row's last valid
-block: past it Pallas issues no new DMA and ``pl.when`` skips the
-compute, so a row reads ``ceil(len / tk)`` blocks.
+the K/V ``index_map`` returns ``(layer, b, 0, min(j, last[b]))``, the
+block index clamped to the row's last valid block: past it Pallas
+starts no new DMA and ``pl.when`` skips the compute, so a row reads
+``ceil(len / tk)`` blocks.
 
 Arithmetic, per block:
   scores  on the MXU: a block-diagonal ``(H, KV * hd)`` query matrix
@@ -64,35 +71,57 @@ def stored_t_minor(shape) -> bool:
 
 
 def _stored(x):
-    """(B, T, KV, hd) -> (B, KV * hd, T): the bytes of a T-minor cache."""
-    B, T, KV, hd = x.shape
-    return x.transpose(0, 2, 3, 1).reshape(B, KV * hd, T)
+    """(..., T, KV, hd) -> (..., KV * hd, T): the bytes of a T-minor
+    cache."""
+    *lead, T, KV, hd = x.shape
+    return jnp.moveaxis(x, len(lead), -1).reshape(*lead, KV * hd, T)
 
 
-def write_rows(cache, new, index):
+def _logical(x, kv_heads):
+    """The inverse of ``_stored``."""
+    *lead, D, T = x.shape
+    return jnp.moveaxis(x.reshape(*lead, kv_heads, D // kv_heads, T),
+                        -1, len(lead))
+
+
+def write_rows(cache, new, index, layer=None):
     """``cache`` (B, T, KV, hd) with ``new`` (B, S, KV, hd) written at
-    positions ``[index[b], index[b] + S)`` of each row ``b``.
+    positions ``[index[b], index[b] + S)`` of each row ``b`` (``index``
+    one int a row, or one for all).  With ``layer``, ``cache`` holds
+    every layer's cache stacked, (L, B, T, KV, hd), and the rows go into
+    layer ``layer``, in place.
 
     A T-minor cache is written through the view the kernel reads, so
     XLA keeps it in its stored layout (a write on the logical shape
     makes it relayout the whole cache before and after).
     """
-    B, T, KV, hd = cache.shape
+    B, S, KV, hd = new.shape
     new = new.astype(cache.dtype)
+    index = jnp.asarray(index, jnp.int32)
     if not stored_t_minor(cache.shape):
+        # only a T-minor cache is stacked or written at a shared index
+        assert layer is None and index.ndim == 1
+
         def row(c, u, i):
             return jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
         return jax.vmap(row)(cache, new, index)
-    out, rows = _stored(cache), _stored(new)
-    # one in-place write a row: a vmapped write runs as a scatter loop,
-    # 229 against 168 us for one layer's keys at 24 x 1024 on a v5e
-    for b in range(B):
-        out = jax.lax.dynamic_update_slice(out, rows[b:b + 1],
-                                           (b, 0, index[b]))
-    return out.reshape(B, KV, hd, T).transpose(0, 3, 1, 2)
+    out = _stored(cache if layer is not None else cache[None])
+    rows = _stored(new)[None]                      # (1, B, KV * hd, S)
+    lid = jnp.asarray(0 if layer is None else layer, jnp.int32)
+    if index.ndim == 0:
+        out = jax.lax.dynamic_update_slice(out, rows, (lid, 0, 0, index))
+    else:
+        # one in-place write a row: a vmapped write runs as a scatter
+        # loop, 229 against 168 us for one layer's keys at 24 x 1024 on
+        # a v5e
+        for b in range(B):
+            out = jax.lax.dynamic_update_slice(
+                out, rows[:, b:b + 1], (lid, b, 0, index[b]))
+    out = _logical(out, KV)
+    return out if layer is not None else out[0]
 
 
-def _kernel(last_ref, len_ref, qpos_ref, wact_ref,
+def _kernel(last_ref, len_ref, qpos_ref, wact_ref, lid_ref,
             q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *, tk: int, T: int, kv_heads: int,
             group: int, head_dim: int, scale: float, causal: bool,
@@ -157,12 +186,15 @@ def _kernel(last_ref, len_ref, qpos_ref, wact_ref,
 
 def decode_attention(q, k, v, *, kv_len, q_positions, causal: bool = True,
                      window: int | None = None, window_active=None,
-                     softcap: float | None = None, interpret: bool = False):
+                     softcap: float | None = None, layer=None,
+                     interpret: bool = False):
     """Attention of one query token per row over that row's cache.
 
     q: (B, 1, H, hd); k/v: (B, T, KV, hd), the cache as stored (KV
     divides H: grouped-query heads; read in place where
-    ``stored_t_minor``); kv_len, q_positions: ints, one per
+    ``stored_t_minor``), or with ``layer`` (an int, traced or not) every
+    layer's cache stacked, (L, B, T, KV, hd), of which layer ``layer``
+    is read; kv_len, q_positions: ints, one per
     row or one for all.  Row ``b`` attends to cache positions
     ``t < kv_len[b]`` (and, when ``causal``, ``t <= q_positions[b]``;
     with ``window``, ``q_positions[b] - t < window`` unless the traced
@@ -170,7 +202,9 @@ def decode_attention(q, k, v, *, kv_len, q_positions, causal: bool = True,
     ``models.layers.flash_attention`` does for the same arguments.
     """
     B, S, H, hd = q.shape
-    _, T, KV, _ = k.shape
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
+    _, _, T, KV, _ = k.shape
     assert S == 1 and H % KV == 0, (q.shape, k.shape)
     G = H // KV
     tk = min(T, BLOCK)
@@ -183,6 +217,7 @@ def decode_attention(q, k, v, *, kv_len, q_positions, causal: bool = True,
     last = jnp.clip((kv_len - 1) // tk, 0, n_blk - 1)
     wact = jnp.asarray(True if window_active is None else window_active,
                        jnp.int32).reshape(1)
+    lid = jnp.asarray(layer, jnp.int32).reshape(1)
     # block-diagonal queries: row h = k*G + g holds q[h] in columns
     # [k*hd, (k+1)*hd), so one matmul scores every head against a block
     own = jnp.arange(H)[:, None] // G == jnp.arange(KV)[None, :]
@@ -190,8 +225,8 @@ def decode_attention(q, k, v, *, kv_len, q_positions, causal: bool = True,
                     jnp.zeros((), q.dtype)).reshape(B, H, KV * hd)
     qbd = qbd.astype(jnp.promote_types(q.dtype, k.dtype))
 
-    def kv_map(b, j, last_ref, *_):
-        return (b, 0, jnp.minimum(j, last_ref[b]))
+    def kv_map(b, j, last_ref, len_ref, qpos_ref, wact_ref, lid_ref):
+        return (lid_ref[0], b, 0, jnp.minimum(j, last_ref[b]))
 
     kernel = functools.partial(
         _kernel, tk=tk, T=T, kv_heads=KV, group=G, head_dim=hd,
@@ -200,12 +235,12 @@ def decode_attention(q, k, v, *, kv_len, q_positions, causal: bool = True,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(B, n_blk),
             in_specs=[
                 pl.BlockSpec((1, H, KV * hd), lambda b, j, *_: (b, 0, 0)),
-                pl.BlockSpec((1, KV * hd, tk), kv_map),
-                pl.BlockSpec((1, KV * hd, tk), kv_map),
+                pl.BlockSpec((pl.squeezed, 1, KV * hd, tk), kv_map),
+                pl.BlockSpec((pl.squeezed, 1, KV * hd, tk), kv_map),
             ],
             out_specs=pl.BlockSpec((1, H, hd), lambda b, j, *_: (b, 0, 0)),
             scratch_shapes=[pltpu.VMEM((H, tk), jnp.float32),
@@ -216,5 +251,5 @@ def decode_attention(q, k, v, *, kv_len, q_positions, causal: bool = True,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attention",
-    )(last, kv_len, qpos, wact, qbd, _stored(k), _stored(v))
+    )(last, kv_len, qpos, wact, lid, qbd, _stored(k), _stored(v))
     return out.reshape(B, 1, H, hd)

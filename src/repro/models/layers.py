@@ -185,6 +185,17 @@ def _on_one_device(*xs) -> bool:
     return all(jax.typeof(x).sharding.mesh.size <= 1 for x in xs)
 
 
+def decode_kernel_applies(x, k, cache_index, *operands) -> bool:
+    """Whether a step of ``x`` (B, S, ...) against the cache keys ``k``
+    (..., T, KV, hd) runs the decode kernel: one new token a row (S ==
+    1) at a write position, a cache the TPU stores T-minor (readable in
+    place), and a program on one device (``x``, ``k`` and any further
+    ``operands`` of the step).  Read from the shapes alone, of traced or
+    concrete arrays; ``Model.carries_cache`` asks it too."""
+    return (cache_index is not None and x.shape[1] == 1
+            and stored_t_minor(k.shape) and _on_one_device(x, k, *operands))
+
+
 def attention_init(key, d_model: int, n_heads: int, kv_heads: int,
                    head_dim: int, dtype) -> Params:
     ks = jax.random.split(key, 4)
@@ -210,11 +221,17 @@ def attention_apply(p: Params, x: jnp.ndarray, *,
                     cache: Params | None = None,
                     cache_index: jnp.ndarray | None = None,
                     static_cache: bool = False,
+                    layer: jnp.ndarray | None = None,
                     block: int = 512):
     """Returns (out, new_cache).  ``xkv`` switches to cross-attention.
 
     Cache layout: {"k": (B, T_max, KV, hd), "v": ...}; ``cache_index`` is
     the write position (decode step) — None means prefill writes [0, S).
+    With ``layer`` the cache holds every layer's K/V stacked, {"k": (L,
+    B, T_max, KV, hd), ...}: the step writes its rows into layer
+    ``layer`` in place and the decode kernel reads that layer, so the
+    returned cache is the whole stack (only where
+    ``decode_kernel_applies``).
     """
     B, S, _ = x.shape
     src = x if xkv is None else xkv
@@ -235,6 +252,13 @@ def attention_apply(p: Params, x: jnp.ndarray, *,
         k, v = cache["k"], cache["v"]
         kv_positions = jnp.arange(k.shape[1])
         new_cache = cache
+    elif cache is not None and layer is not None:
+        assert decode_kernel_applies(q, cache["k"], cache_index)
+        k_all = write_rows(cache["k"], k, cache_index, layer=layer)
+        v_all = write_rows(cache["v"], v, cache_index, layer=layer)
+        kv_len = cache_index + S
+        new_cache = {"k": k_all, "v": v_all}
+        k, v = k_all, v_all
     elif cache is not None:
         if cache_index is not None and \
                 getattr(cache_index, "ndim", 0) == 1:
@@ -264,14 +288,14 @@ def attention_apply(p: Params, x: jnp.ndarray, *,
     else:
         new_cache = None
 
-    if (cache is not None and not static_cache and cache_index is not None
-            and S == 1 and stored_t_minor(k.shape) and _on_one_device(q, k)):
+    if (cache is not None and not static_cache
+            and decode_kernel_applies(q, k, cache_index)):
         # one new token per row against its cache: the decode kernel
         # reads only each row's valid blocks, in the cache's own layout
         out = decode_attention(
             q, k, v, kv_len=kv_len, q_positions=q_positions, causal=causal,
             window=window, window_active=window_active, softcap=softcap,
-            interpret=interpret_default())
+            layer=layer, interpret=interpret_default())
     else:
         kv_len_arr = (None if kv_len is None
                       else jnp.asarray(kv_len, jnp.int32).reshape(-1))

@@ -170,7 +170,7 @@ class Model:
 
     # -------------------------------------------------------- layer bodies
     def _attn_block(self, lp, x, positions, *, window_flag=None,
-                    cache=None, cache_index=None, remat=False):
+                    cache=None, cache_index=None, layer=None, remat=False):
         cfg = self.cfg
 
         def body(lp, x, cache):
@@ -181,7 +181,7 @@ class Model:
                 q_positions=positions, causal=True,
                 window=cfg.window, window_active=window_flag,
                 softcap=cfg.attn_softcap,
-                cache=cache, cache_index=cache_index)
+                cache=cache, cache_index=cache_index, layer=layer)
             x = x + h
             if "moe" in lp:
                 h, aux = MOE.moe_apply(lp["moe"], cfg,
@@ -215,6 +215,24 @@ class Model:
         if fam in ("dense", "moe", "vlm"):
             n = cfg.layers
             layer_ids = jnp.arange(n)
+            if caches is not None and self.carries_cache(
+                    params, {"layers": caches}, x, cache_index):
+                # the decode kernel reads a layer of the stacked cache in
+                # place: carry the whole stack and write each layer's new
+                # rows into it, so no layer is sliced out or stacked back
+                def carried_body(carry, inp):
+                    x, cache = carry
+                    lp, lid = inp
+                    wf = (lid % 2 == 0) if cfg.alt_local_global else None
+                    y, cache, aux = self._attn_block(
+                        lp, x, positions, window_flag=wf, cache=cache,
+                        cache_index=cache_index, layer=lid, remat=remat)
+                    return (y, cache), aux
+
+                (x, new_caches), auxs = jax.lax.scan(
+                    carried_body, (x, caches),
+                    (params["layers"], layer_ids))
+                return x, new_caches, jnp.sum(auxs)
 
             def scan_body(carry, inp):
                 x = carry
@@ -457,6 +475,19 @@ class Model:
                 params, x, pos, caches=cache["layers"], cache_index=index)
             new_cache = {"layers": new_l}
         return self._lm_logits(params, x), new_cache
+
+    def carries_cache(self, params, cache, x, index) -> bool:
+        """Whether a ``decode_step`` of ``x`` (tokens (B, S), or their
+        activations) against ``cache`` at write position(s) ``index``
+        carries the stacked KV cache through the layer scan and updates
+        it in place: where the decode kernel reads it
+        (``layers.decode_kernel_applies``), with the weights on the same
+        one device.  Every other step slices each layer out and stacks it
+        back.  Traced or concrete arguments: the engine donates the cache
+        exactly where this holds."""
+        return (self.cfg.family in ("dense", "moe", "vlm")
+                and L.decode_kernel_applies(x, cache["layers"]["k"], index,
+                                            params["embed"]["e"]))
 
     # -------------------------------------------------------------- caches
     def init_cache(self, batch: int, max_len: int) -> dict:

@@ -50,13 +50,25 @@ def gumbel_argmax(logits, temperature: float, key):
     return jnp.argmax(logits / temperature + g, axis=-1).astype(jnp.int32)
 
 
-def _named_jit(fn, name: str):
-    """``jax.jit(fn)`` whose program is named ``jit_<name>``: the same
-    computation, told apart from other jits of ``fn`` in a profile."""
+def donate_default() -> bool:
+    """Whether the engine's slot-decode and graft programs donate the
+    slot cache they are given.  On an accelerator they do: the slot
+    cache is most of the device's memory, and a step that updates it in
+    place holds one copy of it, not two.  On the CPU backend (tests and
+    rehearsals) they do not, so code that keeps a cache past a step
+    still reads it (``chipbench``'s fault that leaves the decode state
+    unchanged does); a test turns donation on there by patching this."""
+    return jax.default_backend() != "cpu"
+
+
+def _named_jit(fn, name: str, **jit_kw):
+    """``jax.jit(fn, **jit_kw)`` whose program is named ``jit_<name>``:
+    the same computation, told apart from other jits of ``fn`` in a
+    profile."""
     def named(*args):
         return fn(*args)
     named.__name__ = named.__qualname__ = name
-    return jax.jit(named)
+    return jax.jit(named, **jit_kw)
 
 
 def insert_row(slot_cache, row_cache, slot):
@@ -82,11 +94,24 @@ class Engine:
             lambda p, b: model.prefill(p, b, max_len=cfg.cache_len))
         # chunk-capable, slot-indexable (see model.decode_step); one
         # compiled program per distinct (B, S) / index-rank signature,
-        # jitted under the name of the primitive that runs it
+        # jitted under the name of the primitive that runs it.  Where the
+        # model carries the slot cache through its layer scan
+        # (``Model.carries_cache``), ``decode_slots`` donates it and XLA
+        # updates it in place.  A scan that slices each layer out of a
+        # donated argument copies the whole cache once more, so there,
+        # in the verify step and in the prefill chunk (whose cache the
+        # scheduler and the prefix cache keep) the cache is kept.  The
+        # graft writes one row in place and always donates.
+        donate = donate_default()
         self._decode = jax.jit(model.decode_step)       # generate()
         self._prefill_chunk = _named_jit(model.decode_step, "prefill_chunk")
-        self._decode_slots = _named_jit(model.decode_step, "decode_slots")
-        self._insert = jax.jit(insert_row)
+        self._decode_slots_kept = _named_jit(model.decode_step,
+                                             "decode_slots")
+        self._decode_slots = (
+            _named_jit(model.decode_step, "decode_slots", donate_argnums=1)
+            if donate else self._decode_slots_kept)
+        self._insert = jax.jit(insert_row,
+                               donate_argnums=(0,) if donate else ())
 
         # speculative-decoding verify: one decode_step over a whole
         # draft window, greedy-argmaxed *inside* the jit so only (B, W)
@@ -116,9 +141,16 @@ class Engine:
     def decode_slots(self, cache, tokens, positions):
         """One decode step with per-row write positions (B,); rows are
         fully independent — inactive slots may carry garbage, their
-        writes land below/at their own positions only."""
-        return self._decode_slots(self.params, cache, jnp.asarray(tokens),
-                                  jnp.asarray(positions, jnp.int32))
+        writes land below/at their own positions only.  Returns (logits,
+        cache).  The step may consume the ``cache`` passed in (donated
+        and updated in place where the model carries it, see
+        ``donate_default``): use the one returned."""
+        tokens = jnp.asarray(tokens)
+        positions = jnp.asarray(positions, jnp.int32)
+        step = (self._decode_slots if self.model.carries_cache(
+            self.params, cache, tokens, positions)
+            else self._decode_slots_kept)
+        return step(self.params, cache, tokens, positions)
 
     def verify_step(self, cache, tokens, positions):
         """One speculative-verify step: decode ``tokens`` (B, W) — per
@@ -135,7 +167,10 @@ class Engine:
                             jnp.asarray(positions, jnp.int32))
 
     def insert_row(self, slot_cache, row_cache, slot: int):
-        """Graft a B=1 prefill cache into row `slot` of the slot cache."""
+        """Graft a B=1 prefill cache into row `slot` of the slot cache.
+        The graft consumes ``slot_cache`` (donated and written in place,
+        see ``donate_default``): use the one returned.  ``row_cache``
+        stays valid."""
         return self._insert(slot_cache, row_cache,
                             jnp.asarray(slot, jnp.int32))
 

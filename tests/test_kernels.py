@@ -11,7 +11,8 @@ from repro.core.tpu_mapping import (MXU, VMEM_BUDGET_BYTES, FusedTilePlan,
                                     plan_gemm_tiling, tpu_spec)
 from repro.kernels.goma_gemm import goma_matmul
 from repro.kernels.ops import fused_mlp, fused_mlp_composition, gemm
-from repro.kernels.decode_attention import BLOCK, decode_attention
+from repro.kernels.decode_attention import (BLOCK, decode_attention,
+                                           write_rows)
 from repro.kernels.ref import (decode_attention_ref, matmul_ref, ssd_ref,
                                wkv6_ref)
 from repro.models.layers import flash_attention
@@ -377,3 +378,98 @@ def test_decode_kernel_runs_only_in_single_token_decode(head_dim):
     assert ("pallas_call" in decode) == in_place
     assert ("decode_attention" in decode) == in_place
     assert "pallas_call" not in chunk
+
+
+# --- the same kernel over every layer's cache, stacked (L, B, T, KV, hd) --
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_decode_attention_reads_a_layer_of_the_stacked_cache(layer):
+    """Given the stacked cache and a (traced) layer index, the kernel
+    reads that layer's blocks: it matches the reference on the slice."""
+    L_, T, G, KV, hd, tk = 3, 300, 2, 2, 64, BLOCK
+    lens = [1, tk - 1, tk, tk + 1, T]
+    B = len(lens)
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = _rand(ks[0], (B, 1, KV * G, hd), jnp.float32) * 10
+    k = _rand(ks[1], (L_, B, T, KV, hd), jnp.float32) * 10
+    v = _rand(ks[2], (L_, B, T, KV, hd), jnp.float32) * 10
+    kv_len = jnp.asarray(lens, jnp.int32)
+
+    @jax.jit
+    def kernel(q, k, v, kv_len, lid):
+        return decode_attention(q, k, v, kv_len=kv_len,
+                                q_positions=kv_len - 1, window=100,
+                                layer=lid, interpret=True)
+
+    out = kernel(q, k, v, kv_len, jnp.int32(layer))
+    ref = decode_attention_ref(q, k[layer], v[layer], kv_len=kv_len,
+                               q_positions=kv_len - 1, window=100)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("index", ["rows", "shared"])
+def test_write_rows_into_a_layer_of_the_stacked_cache(index):
+    """Rows written into layer ``l`` of the stacked cache land where the
+    per-layer write puts them, and no other layer changes."""
+    L_, B, T, KV, hd, S = 3, 4, 40, 2, 16, 1
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    cache = _rand(ks[0], (L_, B, T, KV, hd), jnp.bfloat16)
+    new = _rand(ks[1], (B, S, KV, hd), jnp.float32)
+    pos = (jnp.asarray([0, 7, 38, 39], jnp.int32) if index == "rows"
+           else jnp.int32(21))
+    rows = jnp.broadcast_to(pos, (B,))
+    for lid in range(L_):
+        got = jax.jit(write_rows)(cache, new, pos, jnp.int32(lid))
+        want = cache.at[lid].set(write_rows(cache[lid], new, rows))
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        assert float(jnp.abs(got[lid] - cache[lid]).max()) > 0
+
+
+# (smoke config, write positions): a window switched per layer with soft
+# caps and 2 query heads a KV head (gemma2), 4 a KV head (llama3), MoE
+# layers (deepseek); one position a row, mixed, and one for all rows
+CARRY_CASES = [("gemma2-27b", "rows"), ("llama3-8b", "rows"),
+               ("deepseek-moe-16b", "rows"), ("gemma2-27b", "shared")]
+
+
+@pytest.mark.parametrize("case", CARRY_CASES, ids="-".join)
+def test_carried_layer_scan_matches_the_sliced_scan(monkeypatch, case):
+    """A two-layer decode step that carries the stacked cache through the
+    layer scan gives the logits and cache of the scan that slices each
+    layer out and stacks it back (forced by keeping the stacked cache
+    from the kernel's rule, so each slice still runs the kernel)."""
+    import repro.models.layers as layers
+    from repro.configs import get_config
+    from repro.models import build_model
+    arch, index = case
+    model = build_model(get_config(arch, smoke=True))
+    params = model.init_params(jax.random.PRNGKey(0))
+    B, T = 4, 40
+    ks = iter(jax.random.split(jax.random.PRNGKey(6), 3))
+    cache = jax.tree.map(lambda a: _rand(next(ks), a.shape, a.dtype) * 10,
+                         model.init_cache(B, T))
+    toks = jnp.asarray([[3], [1], [4], [1]], jnp.int32)
+    pos = (jnp.asarray([0, 5, 21, 39], jnp.int32) if index == "rows"
+           else jnp.int32(21))
+
+    def step():
+        def fn(*a):                 # a new function: traced afresh
+            return model.decode_step(*a)
+        carries = [e.params["num_carry"] for e in jax.make_jaxpr(fn)(
+            params, cache, toks, pos).jaxpr.eqns
+            if e.primitive.name == "scan"]
+        return carries, jax.jit(fn)(params, cache, toks, pos)
+
+    carries, (logits, new) = step()
+    assert carries == [3]                   # x and the stacked K and V
+    rule = layers.decode_kernel_applies
+    monkeypatch.setattr(layers, "decode_kernel_applies",
+                        lambda x, k, *a: k.ndim == 4 and rule(x, k, *a))
+    carries, (want_logits, want) = step()
+    assert carries == [1]
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

@@ -152,6 +152,81 @@ def test_arrival_schedules_match_oracle(setup, schedule):
     _check_against_oracle(results, reqs, oracle)
 
 
+@pytest.mark.parametrize("mode", ["decode", "ngram_spec", "model_spec",
+                                  "prefix"])
+def test_donated_slot_cache_serves_the_same_tokens(setup, monkeypatch,
+                                                   mode):
+    """With the engine's programs donating the cache they are given (as
+    on an accelerator), a scheduler run serves the oracle's tokens: every
+    caller uses the cache a step returns, and none reads a donated one
+    (JAX raises on a donated buffer on the CPU too).  The slot cache the
+    run started from is gone; the one it ends with is live."""
+    import repro.serving.engine as engine_mod
+    from repro.serving.router import ModelDrafter, NgramDrafter, PrefixCache
+    cfg, model, params, _, oracle = setup
+    monkeypatch.setattr(engine_mod, "donate_default", lambda: True)
+
+    def donating():
+        return Engine(model, params,
+                      ServeConfig(max_new_tokens=10, cache_len=CACHE))
+    engine = donating()
+    rng = np.random.default_rng(11)
+    shared = rng.integers(0, cfg.vocab, (16,))
+    reqs = [Request(req_id=i,
+                    tokens=np.concatenate([shared[:16 if mode == "prefix"
+                                                  else 2 + i],
+                                           rng.integers(0, cfg.vocab,
+                                                        (3 + i,))]),
+                    max_new_tokens=4 + i, arrival_s=0.02 * i)
+            for i in range(5)]
+    kw = {}
+    if mode.endswith("spec"):
+        kw["drafter"] = (NgramDrafter() if mode == "ngram_spec"
+                         else ModelDrafter(donating()))
+    if mode == "prefix":
+        kw["prefix_cache"] = PrefixCache(16)
+    clock = TraceClock()
+    sched = ContinuousScheduler(
+        engine, SchedConfig(slots=3, chunk_widths=(4, 16),
+                            spec_width=4 if mode.endswith("spec") else None),
+        clock=clock.now, **kw)
+    first = jax.tree.leaves(sched.slot_cache)
+    results = replay(sched, reqs, clock)
+    _check_against_oracle(results, reqs, oracle)
+    assert all(leaf.is_deleted() for leaf in first)
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves(sched.slot_cache))
+
+
+@pytest.mark.parametrize("head_dim", [16, 128])
+def test_engine_donates_the_slot_cache_where_the_model_carries_it(
+        monkeypatch, head_dim):
+    """With donation on, the slot decode consumes the cache where the
+    model carries it through the layer scan (head_dim 16: stored
+    T-minor, read by the kernel) and keeps it where the scan slices it
+    (head_dim 128); the verify step always keeps it, the graft always
+    consumes it."""
+    import jax.numpy as jnp
+
+    import repro.serving.engine as engine_mod
+    monkeypatch.setattr(engine_mod, "donate_default", lambda: True)
+    cfg = get_config("llama3-8b", smoke=True).replace(head_dim=head_dim)
+    model = build_model(cfg)
+    engine = Engine(model, model.init_params(jax.random.PRNGKey(0)),
+                    ServeConfig(cache_len=32))
+
+    def gone(cache):
+        return {leaf.is_deleted() for leaf in jax.tree.leaves(cache)}
+    pos = jnp.asarray([3, 9], jnp.int32)
+    cache = engine.new_cache(2)
+    _, out = engine.decode_slots(cache, jnp.ones((2, 1), jnp.int32), pos)
+    assert gone(cache) == {head_dim % 128 != 0} and gone(out) == {False}
+    _, _, new = engine.verify_step(out, jnp.ones((2, 3), jnp.int32), pos)
+    assert gone(out) == {False}
+    engine.insert_row(new, engine.new_cache(1), 1)
+    assert gone(new) == {True}
+
+
 def test_streaming_callbacks_and_metrics(setup):
     cfg, model, params, engine, oracle = setup
     rng = np.random.default_rng(3)

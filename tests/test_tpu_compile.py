@@ -10,8 +10,10 @@ one process at a time may load the TPU library, and the test workers all
 import this file.
 """
 import dataclasses
+import functools
 import itertools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,18 +149,12 @@ DECODE_STEPS = [("stablelm-1.6b", 24, 1024, True),
                 ("llama3-8b", 8, 2048, False)]
 
 
-@pytest.mark.parametrize("step", DECODE_STEPS,
-                         ids=[s[0] for s in DECODE_STEPS])
-def test_decode_step_keeps_the_cache_in_place(one_chip, monkeypatch, step):
-    """A per-slot decode step (two layers, full width): the row write,
-    the attention and the layer scan's slicing leave the cache in its
-    stored layout — no copy of a layer's cache anywhere in the
-    program."""
-    import repro.models.layers as layers
+def _decode_program(one_chip, arch, B, T, jit):
+    """The compiled text of ``jit(model.decode_step)`` for ``arch`` at
+    full width with two layers, B slots of T positions, one write
+    position a row; and the number of leaves of its parameters."""
     from repro.configs import get_config
     from repro.models import build_model
-    monkeypatch.setattr(layers, "interpret_default", lambda: False)
-    arch, B, T, kernel = step
     cfg = get_config(arch).replace(
         param_dtype="bfloat16", compute_dtype="bfloat16", layers=2)
     model = build_model(cfg)
@@ -167,11 +163,31 @@ def test_decode_step_keeps_the_cache_in_place(one_chip, monkeypatch, step):
         return jax.tree.map(
             lambda a: _sds(one_chip, a.shape, a.dtype), tree)
 
-    text = jax.jit(model.decode_step).lower(
-        placed(jax.eval_shape(model.init_params, jax.random.PRNGKey(0))),
-        placed(jax.eval_shape(lambda: model.init_cache(B, T))),
+    params = placed(jax.eval_shape(model.init_params,
+                                   jax.random.PRNGKey(0)))
+    text = jit(model.decode_step).lower(
+        params, placed(jax.eval_shape(lambda: model.init_cache(B, T))),
         _sds(one_chip, (B, 1), jnp.int32),
         _sds(one_chip, (B,), jnp.int32)).compile().as_text()
+    return cfg, text, len(jax.tree.leaves(params))
+
+
+@pytest.mark.parametrize("step", DECODE_STEPS,
+                         ids=[s[0] for s in DECODE_STEPS])
+def test_decode_step_keeps_the_cache_in_place(one_chip, monkeypatch, step):
+    """A per-slot decode step (two layers, full width): the row write,
+    the attention and the layer scan leave the cache in its stored
+    layout — no copy of a layer's cache anywhere in the program.  The
+    kernel's step carries the stacked cache through the scan, so it is
+    compiled as the engine runs it, the cache donated (else the carry
+    starts from one copy of the argument); llama3-8b's keeps the
+    sliced scan and is compiled as it always was."""
+    import repro.models.layers as layers
+    monkeypatch.setattr(layers, "interpret_default", lambda: False)
+    arch, B, T, kernel = step
+    jit = (functools.partial(jax.jit, donate_argnums=1) if kernel
+           else jax.jit)
+    cfg, text, _ = _decode_program(one_chip, arch, B, T, jit)
     assert ("tpu_custom_call" in text) == kernel
     kv_hd = [cfg.kv_heads, cfg.head_dim]
 
@@ -181,6 +197,54 @@ def test_decode_step_keeps_the_cache_in_place(one_chip, monkeypatch, step):
         return (math.prod(dims) >= B * T * math.prod(kv_hd)
                 and (dims[-2:] == kv_hd or T in dims))
     assert [c for c in _copies(text) if layer_cache(c)] == []
+
+
+def _instructions(text):
+    """(name, result dims, opcode, operand names) of every array-valued
+    instruction of a compiled module, fused computations included."""
+    pat = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(([^)]*)\)")
+    out = []
+    for line in text.splitlines():
+        m = pat.match(line)
+        if m:
+            name, dims, op, args = m.groups()
+            out.append((name, [int(d) for d in dims.split(",") if d], op,
+                        re.findall(r"%([\w.-]+)", args)))
+    return out
+
+
+def test_donated_decode_slots_updates_the_cache_in_place(one_chip,
+                                                         monkeypatch):
+    """The engine's ``decode_slots`` program for stablelm-1.6b at full
+    width (two layers, 24 slots x 1024), its cache donated: the cache
+    leaves are aliased from argument to result, and nothing in it —
+    copy, slice or update, fused or not — moves a block the size of a
+    layer's cache or more: the scan carries the stacked cache, writes
+    each layer's rows into it and the kernel reads the layer in place."""
+    import repro.models.layers as layers
+    from repro.serving.engine import _named_jit
+    monkeypatch.setattr(layers, "interpret_default", lambda: False)
+    B, T = 24, 1024
+    cfg, text, n_params = _decode_program(
+        one_chip, "stablelm-1.6b", B, T,
+        lambda f: _named_jit(f, "decode_slots", donate_argnums=1))
+    assert text.startswith("HloModule jit_decode_slots")
+    assert "tpu_custom_call" in text
+    alias = re.search(r"input_output_alias=\{(.*?) \}", text).group(1)
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}", alias)
+    assert sorted(map(int, aliased)) == [n_params, n_params + 1]  # K, V
+    layer = B * T * cfg.kv_heads * cfg.head_dim
+    ins = _instructions(text)
+    dims = {name: d for name, d, _, _ in ins}
+    moved = []
+    for name, d, op, args in ins:
+        if op in ("copy", "dynamic-slice"):
+            moved.append((name, d))
+        elif op == "dynamic-update-slice":     # the update, not the buffer
+            moved.append((name, dims[args[1]]))
+    assert any(op == "dynamic-update-slice" for _, _, op, _ in ins)
+    assert [(n, d) for n, d in moved if math.prod(d) >= layer] == []
 
 
 def test_sharded_decode_step_keeps_the_scan(topo, monkeypatch):
