@@ -1,6 +1,6 @@
 """Certified (energy, delay) Pareto frontiers -> BENCH_pareto.json.
 
-Three gates, all asserted:
+Two gates, both asserted:
 
 1. **Frontier soundness.**  For every (GEMM, spec) pair in the sweep,
    ``core.solver.solve_pareto`` (epsilon-constraint over the achievable
@@ -13,15 +13,7 @@ Three gates, all asserted:
    (same mapping, same objective scalar) — stored plan identities are
    untouched by the whole feature.
 
-2. **Zero-solve SLO serving.**  A continuous-batching scheduler with
-   ``latency_slo_ns`` set prewarms every bucketed shape's frontier into
-   the plan store, fixes its per-shape point selection, and then serves
-   traffic with zero steady-state solver invocations; a second scheduler
-   constructed from the same store also makes zero solver calls
-   (frontiers rehydrate whole).  Token streams equal the no-SLO
-   scheduler's exactly.
-
-3. **Calibration regression gate.**  ``obs.calibrate.fit_rows`` on a
+2. **Calibration regression gate.**  ``obs.calibrate.fit_rows`` on a
    deterministic synthetic fidelity workload must cut the held-out
    delay-prediction error vs the compute-only baseline (the gate
    ``plan calibrate`` enforces).
@@ -102,73 +94,6 @@ def frontier_case(gemm: Gemm, hw_name: str, *,
     }
 
 
-def serving_slo_case(*, slo_ns: float = 1e9) -> dict:
-    """Zero-solve SLO serving on the llama3 smoke config."""
-    import tempfile
-
-    import jax
-    import numpy as np
-
-    from repro.configs import get_config
-    from repro.core import tpu_mapping
-    from repro.core.solver import solver_stats
-    from repro.models import build_model
-    from repro.planner import PlanStore
-    from repro.serving import Engine, ServeConfig
-    from repro.serving.sched import (ContinuousScheduler, Request,
-                                     SchedConfig)
-
-    cfg = get_config("llama3-8b", smoke=True)
-    model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
-    sc = ServeConfig(max_new_tokens=6, cache_len=256)
-
-    def requests(n=2, max_new=4):
-        rng = np.random.default_rng(0)
-        return [Request(req_id=i,
-                        tokens=rng.integers(0, cfg.vocab, (12,)).astype(
-                            np.int32),
-                        max_new_tokens=max_new) for i in range(n)]
-
-    with tempfile.TemporaryDirectory() as d:
-        try:
-            slo_cfg = SchedConfig(slots=2, chunk_widths=(8, 32),
-                                  latency_slo_ns=slo_ns)
-            engine = Engine(model, params, sc, plan_store=PlanStore(d))
-            sched = ContinuousScheduler(engine, slo_cfg)
-            n_points = len(sched.slo_points)
-            calls0 = solver_stats()["calls"]
-            slo_results = sched.run(requests())
-            steady = solver_stats()["calls"] - calls0
-
-            # warm restart from the same store: frontiers rehydrate, the
-            # constructor itself makes zero solver calls
-            tpu_mapping.set_plan_store(None)
-            tpu_mapping.plan_gemm_tiling.cache_clear()
-            calls1 = solver_stats()["calls"]
-            engine2 = Engine(model, params, sc, plan_store=PlanStore(d))
-            sched2 = ContinuousScheduler(engine2, slo_cfg)
-            warm_calls = solver_stats()["calls"] - calls1
-
-            # token identity vs the no-SLO scheduler
-            tpu_mapping.set_plan_store(None)
-            tpu_mapping.plan_gemm_tiling.cache_clear()
-            base = ContinuousScheduler(
-                Engine(model, params, sc),
-                SchedConfig(slots=2, chunk_widths=(8, 32)))
-            base_results = base.run(requests())
-        finally:
-            tpu_mapping.set_plan_store(None)
-            tpu_mapping.plan_gemm_tiling.cache_clear()
-    slo_tokens = {r.req_id: list(r.tokens) for r in slo_results}
-    base_tokens = {r.req_id: list(r.tokens) for r in base_results}
-    return {"slo_ns": slo_ns, "slo_points": n_points,
-            "steady_state_solves": int(steady),
-            "warm_restart_solves": int(warm_calls),
-            "warm_restart_points": len(sched2.slo_points),
-            "tokens_identical": slo_tokens == base_tokens}
-
-
 def calibration_case() -> dict:
     """Deterministic synthetic workload: measured time = compute term +
     a DRAM-bandwidth term the compute-only baseline cannot express; the
@@ -226,16 +151,6 @@ def run(smoke: bool) -> dict:
                                   res.points[-1].delay_ns * 1.001)
     assert tight is not None and tight.delay_ns < res.points[0].delay_ns
 
-    serving = serving_slo_case()
-    emit("pareto_serving_slo", 0.0,
-         f"points={serving['slo_points']} "
-         f"steady_solves={serving['steady_state_solves']} "
-         f"warm_restart_solves={serving['warm_restart_solves']} "
-         f"tokens_identical={serving['tokens_identical']}")
-    assert serving["steady_state_solves"] == 0, serving
-    assert serving["warm_restart_solves"] == 0, serving
-    assert serving["tokens_identical"], serving
-
     cal = calibration_case()
     emit("pareto_calibration", 0.0,
          f"passes={cal['passes']} improvement={cal['improvement']:.3f} "
@@ -247,8 +162,7 @@ def run(smoke: bool) -> dict:
     out = {"schema": 1, "smoke": smoke, "hw": list(HW_NAMES),
            "n_cases": len(rows),
            "n_multi_point": len(multi),
-           "frontiers": rows, "serving_slo": serving,
-           "calibration": cal}
+           "frontiers": rows, "calibration": cal}
     BENCH_PATH.write_text(json.dumps(out, indent=1) + "\n")
     print(f"wrote {BENCH_PATH}")
     return out
@@ -263,7 +177,7 @@ def main() -> None:
     print(f"pareto {'smoke ' if args.smoke else ''}OK: "
           f"{out['n_cases']} frontiers verified "
           f"({out['n_multi_point']} multi-point), endpoint bit-match "
-          f"everywhere, SLO serving zero-solve, calibration gate passes")
+          f"everywhere, calibration gate passes")
 
 
 if __name__ == "__main__":

@@ -1,6 +1,6 @@
 """Serving scale-out gates -> BENCH_scaleout.json.
 
-Four claims, all measured on the smoke config in virtual trace time
+Three claims, all measured on the smoke config in virtual trace time
 (DESIGN.md §Scale-out):
 
   * **router** — a 4-replica ``ReplicaRouter`` (discrete-event replay,
@@ -13,10 +13,7 @@ Four claims, all measured on the smoke config in virtual trace time
     stays bit-identical to the static oracle;
   * **spec** — n-gram speculative decoding with the adaptive verify-
     window ladder delivers >= 1.3x decode tokens/s on long sequential
-    generations, byte-identical to target-only greedy decoding;
-  * **zero-solve** — one donor prewarm pass covers the fleet: steady
-    state across all replicas (prefix grafts and verify windows
-    included) makes zero solver invocations.
+    generations, byte-identical to target-only greedy decoding.
 
     PYTHONPATH=src python benchmarks/bench_scaleout.py             # full
     PYTHONPATH=src python benchmarks/bench_scaleout.py --smoke     # CI
@@ -24,14 +21,13 @@ Four claims, all measured on the smoke config in virtual trace time
 Full mode replays ~1e5 tiny requests through the router gates (the
 scale the DES harness exists for); ``--requests`` scales that down.
 Smoke mode is the CI fast-lane: oracle-identity across all three
-mechanisms plus the fleet zero-solve certificate, no throughput gates
-(CI wall clock is too noisy to gate ratios on).
+mechanisms, no throughput gates (CI wall clock is too noisy to gate
+ratios on).
 """
 from __future__ import annotations
 
 import argparse
 import json
-import tempfile
 import time
 
 import jax
@@ -40,10 +36,7 @@ import numpy as np
 from common import ROOT, emit
 
 from repro.configs import get_config
-from repro.core import tpu_mapping
-from repro.core.solver import reset_solver_stats, solver_stats
 from repro.models import build_model
-from repro.planner import PlanStore
 from repro.serving import Engine, ServeConfig
 from repro.serving.router import (NgramDrafter, PrefixCache, ReplicaRouter,
                                   RouterConfig, spec_generate)
@@ -264,46 +257,6 @@ def bench_spec(cfg, model, params) -> dict:
             "byte_identical": True}
 
 
-def cert_zero_solve(model, params, vocab: int) -> dict:
-    """Gate: donor prewarm covers the fleet — steady state across 4
-    replicas (prefix grafts + spec verify windows included) makes zero
-    solver invocations."""
-    with tempfile.TemporaryDirectory() as td:
-        store = PlanStore(td)
-        engine = Engine(model, params,
-                        ServeConfig(max_new_tokens=6, cache_len=96),
-                        plan_store=store)
-        try:
-            router = ReplicaRouter(
-                engine, RouterConfig(replicas=4, sched=SchedConfig(
-                    slots=2, chunk_widths=(4, 16), spec_width=4)),
-                prefix_cache=PrefixCache(16), drafter=NgramDrafter())
-            assert router.prewarmed_plans > 0
-            for s in router.scheds[1:]:
-                assert s.prewarmed_plans == 0    # donor pass reused
-            misses0 = store.misses
-            reset_solver_stats()
-            trace = shared_prefix_trace(
-                TrafficConfig(n_requests=12, arrival_rate=1e9,
-                              prompt_mix=((1, 8, 1.0),),
-                              max_new_tokens=5, vocab=vocab, seed=3),
-                prefix_len=16)
-            router.route_trace(trace)
-            calls = solver_stats()["calls"]
-            cold_misses = store.misses - misses0
-        finally:
-            engine.plan_store = None
-            tpu_mapping.set_plan_store(None)
-            tpu_mapping.plan_gemm_tiling.cache_clear()
-    emit("scaleout_steady_state_solves", calls,
-         f"4 replicas, prewarmed={router.prewarmed_plans}, "
-         f"cold store misses={cold_misses}")
-    assert calls == 0, f"{calls} solver invocations in steady state"
-    return {"replicas": 4, "prewarmed_plans": router.prewarmed_plans,
-            "steady_state_solver_calls": calls,
-            "steady_state_store_misses": cold_misses}
-
-
 def run(*, n_requests: int = 100_000) -> dict:
     cfg, model, params, engine = _build(ROUTER_CACHE, 4)
     out = {"generated_unix": time.time(), "mode": "full",
@@ -314,16 +267,14 @@ def run(*, n_requests: int = 100_000) -> dict:
     out["prefix"] = bench_prefix(cfg, model, params,
                                  n=max(n_requests // 250, 16))
     out["spec"] = bench_spec(cfg, model, params)
-    out["zero_solve"] = cert_zero_solve(model, params, cfg.vocab)
     BENCH_PATH.write_text(json.dumps(out, indent=1) + "\n")
     print(f"wrote {BENCH_PATH}")
     return out
 
 
 def smoke() -> None:
-    """CI gate: oracle identity across router + prefix + spec, and the
-    fleet zero-solve certificate.  No throughput ratios (CI wall clock
-    is too noisy to gate on)."""
+    """CI gate: oracle identity across router + prefix + spec.  No
+    throughput ratios (CI wall clock is too noisy to gate on)."""
     cfg, model, params, engine = _build(96, 8)
     oracle = Engine(model, params, ServeConfig(max_new_tokens=8,
                                                cache_len=96))
@@ -349,16 +300,14 @@ def smoke() -> None:
     got = list(spec_generate(engine, prompt, NgramDrafter(),
                              max_new_tokens=24))
     assert got == want, (got, want)
-    zero = cert_zero_solve(model, params, cfg.vocab)
     out = {"generated_unix": time.time(), "mode": "smoke",
            "arch": ARCH,
            "router_requests_bit_identical": len(trace),
-           "spec_byte_identical": True, "zero_solve": zero}
+           "spec_byte_identical": True}
     BENCH_PATH.write_text(json.dumps(out, indent=1) + "\n")
     print(f"scaleout smoke OK: {len(trace)}/{len(trace)} routed "
           f"requests bit-identical across 2 replicas (prefix+spec on), "
-          f"spec stream byte-identical, "
-          f"{zero['steady_state_solver_calls']} steady-state solves")
+          f"spec stream byte-identical")
     print(f"wrote {BENCH_PATH}")
 
 

@@ -6,10 +6,9 @@
 Weights are random, drawn from ``--seed``; all work runs in this one
 process, which holds the chip(s).
 
-* ``serve``: the continuous scheduler with a plan store and capture
-  prewarm, built as ``repro.launch.serve --continuous`` builds it,
-  answers seeded requests.  Every request must be served and no prewarm
-  step may fail.
+* ``serve``: the continuous scheduler over an engine with a plan store,
+  built as ``repro.launch.serve --continuous`` builds it, answers seeded
+  requests.  Every request must be served.
 * ``kernels``: the same model with ``fused_mlp`` routes its MLPs through
   the GOMA Pallas kernels.  Its compiled prefill and decode programs must
   hold a Mosaic kernel (``tpu_custom_call``) and agree with the default
@@ -52,7 +51,6 @@ from repro.kernels.wkv6 import wkv6_pallas  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.serve import CHUNK_WIDTHS, continuous_engine  # noqa: E402
 from repro.models import build_model  # noqa: E402
-from repro.obs.registry import get_registry  # noqa: E402
 from repro.planner import PlanStore  # noqa: E402
 from repro.serving import Engine, ServeConfig  # noqa: E402
 from repro.serving.sched import (ContinuousScheduler,  # noqa: E402
@@ -100,23 +98,18 @@ def phase_serve(cfg, params, *, seed: int, n_requests: int = 8,
         prompt_mix=((prompt_lo, prompt_hi, 1.0),),
         max_new_tokens=new_tokens, vocab=cfg.vocab, seed=seed))
     clock = TraceClock()
-    t0 = time.perf_counter()
     sched = ContinuousScheduler(
         eng, SchedConfig(slots=n_requests, chunk_widths=CHUNK_WIDTHS),
-        arch_id=ARCH, clock=clock.now)
-    print(f"serve: prewarm {sched.prewarmed_plans} GEMM tilings in "
-          f"{time.perf_counter() - t0:.2f}s  store={store.stats()}")
+        clock=clock.now)
     results = replay(sched, trace, clock)
     summ = sched.metrics.summary()
-    failures = get_registry().get("sched.prewarm_failures")
     print(f"serve: {summ['served']}/{summ['requests']} served, "
           f"{summ['total_generated_tokens']} tokens, rejected="
           f"{summ['rejected']} expired={summ['expired']} errored="
-          f"{summ['errored']} prewarm_failures={failures}")
+          f"{summ['errored']}  store={store.stats()}")
     # information only, on the trace clock, compilation included
     print(f"serve (info, incl. compile): ttft_p50={summ['ttft_p50_s']}s "
           f"tokens_per_s={summ['tokens_per_s']}")
-    check(failures == 0, f"{failures} prewarm step(s) failed")
     check(len(results) == n_requests
           and summ["served"] == n_requests
           and not (summ["rejected"] or summ["expired"] or summ["errored"]),

@@ -1,19 +1,16 @@
 """Sharded-serving smoke (run as a subprocess with 4 fake devices —
 keeps the main test process at 1 device per the dry-run rule).
 
-Covers the dist subsystem end-to-end on a real mesh: TP-sharded greedy
-serving token-identical to the single-chip oracle with zero steady-state
-solver invocations (TOKENS_OK); sharded-plan prewarm into a store whose
-re-prewarm is all hits and zero solves (PREWARM_OK); and the scheduler's
-mesh_chips deployment path populating the sharded section at
-construction time (SCHED_OK).
+Covers the dist subsystem's serving path on a real mesh: TP-sharded
+greedy serving token-identical to the single-chip oracle with zero
+steady-state solver invocations (TOKENS_OK).  The sharded plan store's
+round trip is covered by tests/test_dist_solver.py.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
 import pathlib
 import sys
-import tempfile
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
                        / "src"))
@@ -26,9 +23,7 @@ from repro.core.solver import solver_stats
 from repro.dist.serve import shard_engine
 from repro.models import build_model
 from repro.obs.registry import get_registry
-from repro.planner.store import PlanStore
 from repro.serving import Engine, ServeConfig
-from repro.serving.sched import ContinuousScheduler, SchedConfig
 
 
 def main():
@@ -57,42 +52,6 @@ def main():
     assert np.array_equal(want, got), (want, got)
     assert get_registry().get("dist.engines_sharded") >= 1
     print("TOKENS_OK", got.shape)
-
-    # ---- sharded prewarm: second pass is all store hits, zero solves ---
-    with tempfile.TemporaryDirectory() as d:
-        store = PlanStore(d)
-        eng = Engine(model, params, sc, plan_store=store)
-        planned = eng.prewarm_sharded_shapes(
-            [(4, cfg.vocab, cfg.d_model), (4, cfg.d_ff, cfg.d_model)],
-            n_chips=4)
-        assert planned > 0, planned
-        assert store.num_sharded() > 0
-        calls0 = solver_stats()["calls"]
-        hits0 = get_registry().get("dist.store_hits")
-        eng.prewarm_sharded_shapes(
-            [(4, cfg.vocab, cfg.d_model), (4, cfg.d_ff, cfg.d_model)],
-            n_chips=4)
-        assert solver_stats()["calls"] == calls0
-        assert get_registry().get("dist.store_hits") > hits0
-        print("PREWARM_OK", planned, store.num_sharded())
-
-    # ---- scheduler mesh_chips deployment populates sharded section -----
-    with tempfile.TemporaryDirectory() as d:
-        store = PlanStore(d)
-        eng = Engine(model, params, sc, plan_store=store)
-        sched = ContinuousScheduler(
-            eng, SchedConfig(slots=2, chunk_widths=(4, 16), mesh_chips=4))
-        assert sched.prewarmed_sharded > 0, sched.prewarmed_sharded
-        assert store.num_sharded() > 0
-        # a second deployment against the same store resolves every
-        # partition + tiling from cache: zero solver invocations
-        calls0 = solver_stats()["calls"]
-        sched2 = ContinuousScheduler(
-            eng, SchedConfig(slots=2, chunk_widths=(4, 16), mesh_chips=4))
-        assert sched2.prewarmed_sharded == sched.prewarmed_sharded
-        assert solver_stats()["calls"] == calls0
-        print("SCHED_OK", sched.prewarmed_sharded)
-
     print("ALL_OK")
 
 

@@ -9,8 +9,7 @@ against the hand-enumerated ``core.workloads`` tables.
 """
 from .plan import (ProgramPlan, capture_model_decode,
                    capture_model_prefill, capture_serving_program,
-                   captured_serving_plan_shape_groups, plan_program,
-                   serving_capture_shapes)
+                   plan_program)
 from .program import (PlanProgram, ProgramChain, ProgramGemm,
                       captured_program, diff_programs, programs_equal)
 from .reference import (capture_spec_decode, capture_spec_prefill,
@@ -23,7 +22,6 @@ __all__ = [
     "capture_model_decode", "capture_model_prefill",
     "capture_serving_program", "capture_spec_decode",
     "capture_spec_prefill", "capture_spec_scenario",
-    "captured_program", "captured_serving_plan_shape_groups",
-    "diff_programs", "harvest_jaxpr", "plan_program", "programs_equal",
-    "serving_capture_shapes",
+    "captured_program", "diff_programs", "harvest_jaxpr", "plan_program",
+    "programs_equal",
 ]

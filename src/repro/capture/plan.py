@@ -8,11 +8,11 @@ misses solved in one pass) — and every detected chain goes through
 The result is a :class:`ProgramPlan`: the ``ModelMappingManifest``
 artifact plus the chain certificates, all zero-gap.
 
-Also hosts the serving-side capture helpers: tracing a ``Model``'s own
+Also hosts the model capture helpers: tracing a ``Model``'s own
 prefill / decode-step programs (shape-level, via ``model.input_specs``
-stand-ins) so ``serving.Engine.prewarm_plans`` and the continuous
-scheduler prewarm exactly the GEMM set the deployed program will
-dispatch, rather than a hand-maintained extraction of it.
+stand-ins) into a PlanProgram, so the GEMM set a deployed program
+dispatches is read off the program itself rather than a hand-maintained
+extraction of it.
 """
 from __future__ import annotations
 
@@ -262,68 +262,8 @@ def capture_model_decode(model, batch: int, cache_len: int, *,
 def capture_serving_program(model, batch: int, prompt_len: int,
                             cache_len: int) -> PlanProgram:
     """The full serving program of one deployment: prefill at
-    prompt_len merged with the batched decode step — the captured
-    replacement for ``planner.batch.serving_plan_shapes``."""
+    prompt_len merged with the batched decode step."""
     prog = capture_model_prefill(model, batch, prompt_len,
                                  cache_len=cache_len)
     return prog.merged(capture_model_decode(model, batch, cache_len),
                        name=f"{model.cfg.name}_serving")
-
-
-def serving_capture_shapes(model, batch: int, prompt_len: int,
-                           cache_len: int) -> list[tuple[int, int, int]]:
-    """Distinct GEMM (M, N, K) shapes the deployment's traced programs
-    dispatch (``Engine.prewarm_plans`` routes through this)."""
-    return capture_serving_program(model, batch, prompt_len,
-                                   cache_len).shapes()
-
-
-def captured_serving_plan_shape_groups(
-        model, *, slots: int, chunk_widths,
-        cache_len: int) -> dict[str, list[tuple[int, int, int]]]:
-    """Per-phase GEMM shape groups of a continuous-batching deployment,
-    read off the model's *own* traced programs: one group per
-    prefill-chunk width (a (1, W) decode_step continuation) plus the
-    slot-batched decode group — the captured counterpart of
-    ``planner.batch.bucketed_serving_plan_shape_groups``, with the same
-    #widths + 1 bound on plan-key groups."""
-    groups = {
-        f"chunk{w}": capture_model_decode(model, 1, cache_len,
-                                          width=w).shapes()
-        for w in chunk_widths}
-    groups["decode"] = capture_model_decode(
-        model, slots, cache_len, width=1, slot_indexed=True).shapes()
-    return groups
-
-
-def captured_spec_plan_shape_groups(
-        model, *, batch: int, cache_len: int,
-        spec_widths, draft_model=None,
-        draft_cache_len: int | None = None
-        ) -> dict[str, list[tuple[int, int, int]]]:
-    """GEMM shape groups of a speculative-decoding deployment, read off
-    the traced programs themselves: one ``verify{W}`` group per draft
-    window width (a (batch, W) slot-indexed decode — the target model's
-    batched verify step), plus — when a draft *model* proposes the
-    tokens — the drafter's own width-1 decode and teacher-forced
-    catch-up programs.  The spec-decode counterpart of
-    ``captured_serving_plan_shape_groups``: prewarming these groups
-    means neither the verify step nor the draft proposals ever invoke
-    the solver in steady state, and the plan-key count stays bounded by
-    the (small, fixed) width ladder."""
-    groups = {
-        f"verify{w}": capture_model_decode(
-            model, batch, cache_len, width=w, slot_indexed=True).shapes()
-        for w in spec_widths}
-    if draft_model is not None:
-        dlen = draft_cache_len if draft_cache_len is not None \
-            else cache_len
-        groups["draft.decode"] = capture_model_decode(
-            draft_model, 1, dlen, width=1, slot_indexed=True).shapes()
-        for w in spec_widths:
-            # after a rejected draft the drafter re-syncs by decoding
-            # the accepted tokens teacher-forced, one chunk per window
-            # width — same program family as the verify widths
-            groups[f"draft.chunk{w}"] = capture_model_decode(
-                draft_model, 1, dlen, width=w).shapes()
-    return groups

@@ -167,11 +167,11 @@ class PlanProgram:
         return [(pc.label, pc.chain, pc.weight) for pc in self.chains]
 
     def shapes(self) -> list[tuple[int, int, int]]:
-        """Distinct (M, N, K) shapes, first-seen order (prewarm sets)."""
+        """Distinct (M, N, K) shapes, first-seen order."""
         return [pg.dims for pg in self.gemms]
 
     def chain_shapes(self) -> list[tuple[int, int, int, int]]:
-        """Distinct (M, FF, K, N2) fused-chain shapes (prewarm sets)."""
+        """Distinct (M, FF, K, N2) fused-chain shapes."""
         out, seen = [], set()
         for pc in self.chains:
             c = pc.chain

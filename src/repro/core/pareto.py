@@ -102,18 +102,18 @@ class ParetoCertificate:
 
 
 def select_frontier_point(points: Sequence[ParetoPoint],
-                          latency_slo_ns: float | None) -> ParetoPoint | None:
-    """SLO-driven frontier selection (shared by serving and the CLI).
+                          slo_ns: float | None) -> ParetoPoint | None:
+    """SLO-driven frontier selection (``plan pareto --slo-ns``).
 
     No SLO ⇒ the energy-optimal endpoint.  With an SLO, the cheapest
-    point meeting ``delay_ns <= latency_slo_ns``; if none meets it, the
-    fastest point (best effort — the SLO is infeasible for this GEMM)."""
+    point meeting ``delay_ns <= slo_ns``; if none meets it, the fastest
+    point (best effort — the SLO is infeasible for this GEMM)."""
     if not points:
         return None
-    if latency_slo_ns is None:
+    if slo_ns is None:
         return points[0]
     for p in points:              # ascending energy
-        if p.delay_ns <= latency_slo_ns:
+        if p.delay_ns <= slo_ns:
             return p
     return min(points, key=lambda p: (p.delay_ns, p.energy_pj))
 

@@ -207,15 +207,12 @@ def decode_chains(spec: LlmSpec, batch: int, cache_len: int) -> list:
 
 def config_decode_chains(cfg, batch: int = 1) -> list:
     """Chains a ``fused_mlp``-routed model will actually *dispatch* in
-    one decode/prefill-chunk step (the serving engine passes its *own*
-    model config, so prewarmed chain shapes match dispatch by
-    construction — smoke variants included).
+    one decode/prefill-chunk step of ``cfg`` (smoke variants included).
 
     Only the gated dense-MLP block routes through the fused op
     (``models.layers.mlp_apply(use_fused=)``); MoE expert GEMMs go
     through ``moe_apply`` and recurrent families have no gated MLP, so
-    those configs contribute none — prewarming chains a deployment
-    never dispatches would just burn startup solves.  (Analytical MoE
+    those configs contribute none.  (Analytical MoE
     chain extraction for the planner/benchmarks lives in
     ``prefill_chains``/``decode_chains``/``mlp_chain``.)"""
     from .fusion import GemmChain
@@ -320,8 +317,8 @@ def arch_gemms(arch_id: str, seq: int = 4096,
 # planning IR (capture.program.PlanProgram).  These are the differential
 # oracle for jaxpr capture — capturing the reference programs of a spec
 # (capture.reference) must reproduce these multisets exactly — and the
-# uniform input every planning consumer (CLI, batch planner, serving
-# prewarm) lowers from.
+# uniform input every planning consumer (CLI, batch planner) lowers
+# from.
 # ---------------------------------------------------------------------------
 
 def prefill_program(spec: LlmSpec, seq: int):

@@ -35,8 +35,9 @@ def main() -> None:
     ap.add_argument("--new-tokens", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--plan-db", default=None,
-                    help="GOMA plan database dir: prewarm kernel tilings "
-                         "through the store (also: $GOMA_PLAN_DB)")
+                    help="GOMA plan database dir: GOMA kernels resolve "
+                         "their tilings through it when traced (also: "
+                         "$GOMA_PLAN_DB)")
     ap.add_argument("--continuous", action="store_true",
                     help="continuous-batching scheduler over a Poisson "
                          "trace instead of one static batch")
@@ -47,17 +48,11 @@ def main() -> None:
     ap.add_argument("--fused-mlp", action="store_true",
                     help="route gated-MLP blocks through the GOMA-chain-"
                          "planned fused Pallas kernel (token-identical; "
-                         "fused plans prewarm through --plan-db)")
+                         "chain plans read through --plan-db)")
     ap.add_argument("--metrics-jsonl", default=None, metavar="PATH",
                     help="--continuous: stream one JSON line per "
                          "scheduler tick (registry counter snapshot + "
                          "live metrics) to PATH")
-    ap.add_argument("--prewarm-source", default="capture",
-                    choices=("capture", "enumerated"),
-                    help="plan prewarm shape source: 'capture' traces "
-                         "this deployment's own prefill/decode programs "
-                         "(jaxpr capture); 'enumerated' uses the hand "
-                         "extraction tables")
     ap.add_argument("--max-queue", type=int, default=None,
                     help="--continuous: admission-queue bound; overflow "
                          "is shed with a terminal REJECTED result")
@@ -71,8 +66,8 @@ def main() -> None:
     ap.add_argument("--replicas", type=int, default=1,
                     help="--continuous: scheduler replica count; > 1 "
                          "routes the trace through the replica router "
-                         "(one shared prewarm pass, least-loaded "
-                         "admission, virtual per-replica clocks)")
+                         "(least-loaded admission, virtual per-replica "
+                         "clocks)")
     ap.add_argument("--prefix-cache", type=int, default=None,
                     metavar="MB",
                     help="--continuous: enable the KV prefix cache with "
@@ -83,8 +78,8 @@ def main() -> None:
                     help="--continuous: speculative decoding drafter: "
                          "'ngram' (prompt-lookup, zero model calls) or "
                          "an arch name served as a draft model through "
-                         "its own capture-prewarmed engine.  Greedy "
-                         "only; streams stay byte-identical")
+                         "its own engine.  Greedy only; streams stay "
+                         "byte-identical")
     ap.add_argument("--spec-width", type=int, default=4,
                     help="--draft-model: verify window width (1 "
                          "committed + spec-width-1 draft tokens)")
@@ -127,13 +122,6 @@ def main() -> None:
         max_new_tokens=args.new_tokens, temperature=args.temperature,
         cache_len=args.prompt_len + args.new_tokens + 8),
         plan_store=store)
-    if store is not None:
-        import time as _t
-        t0 = _t.perf_counter()
-        n = eng.prewarm_plans(args.arch, args.batch, args.prompt_len,
-                              source=args.prewarm_source)
-        print(f"plan prewarm: {n} GEMM tilings in "
-              f"{_t.perf_counter() - t0:.2f}s  store={store.stats()}")
 
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, cfg.vocab,
@@ -228,7 +216,6 @@ def _serve_continuous(args, cfg, model, params, store) -> None:
                 plan_store=store))
     sched_cfg = SchedConfig(slots=args.batch, chunk_widths=widths,
                             temperature=args.temperature,
-                            prewarm_source=args.prewarm_source,
                             max_queue=args.max_queue,
                             shed_on_full=args.max_queue is not None,
                             default_deadline_s=args.deadline_s,
@@ -241,12 +228,7 @@ def _serve_continuous(args, cfg, model, params, store) -> None:
             eng, RouterConfig(replicas=args.replicas, sched=sched_cfg,
                               ttft_slo_s=args.ttft_slo_s,
                               tpot_slo_s=args.tpot_slo_s),
-            arch_id=args.arch if store is not None else None,
             prefix_cache=prefix_cache, drafter=drafter)
-        if store is not None:
-            print(f"plan prewarm (fleet, one pass): "
-                  f"{router.prewarmed_plans} GEMM tilings  "
-                  f"store={store.stats()}")
         results = router.route_trace(trace)
         summ = router.summary()
         print(f"{cfg.name} router x{args.replicas}: {len(results)} "
@@ -261,16 +243,10 @@ def _serve_continuous(args, cfg, model, params, store) -> None:
         return
 
     sched = ContinuousScheduler(
-        eng, sched_cfg,
-        arch_id=args.arch if store is not None else None,
-        clock=clock.now, on_tick=on_tick,
+        eng, sched_cfg, clock=clock.now, on_tick=on_tick,
         prefix_cache=prefix_cache, drafter=drafter)
     sched.metrics.ttft_slo_s = args.ttft_slo_s
     sched.metrics.tpot_slo_s = args.tpot_slo_s
-    if store is not None:
-        print(f"plan prewarm: {sched.prewarmed_plans} GEMM tilings, "
-              f"{sched.prewarmed_chains} fused chains  "
-              f"store={store.stats()}")
     try:
         results = replay(sched, trace, clock)
     finally:

@@ -63,8 +63,6 @@ Resilience namespaces (see ``repro.faults`` and DESIGN.md §Resilience):
                                   cold_resolves}, degraded.sched.{shed,
                                   expired}, degraded.solver.bounded,
                                   degraded.plans.bounded_served
-  sched.prewarm_failures          per-group/per-shape prewarm failures
-                                  that were logged and skipped
 
 Spans (``obs.tracing``) of one scheduler tick, each with ``tick=``:
 

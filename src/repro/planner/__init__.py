@@ -15,18 +15,14 @@ from a library function into a service-shaped component:
   * ``manifest``  — the ``ModelMappingManifest`` build artifact,
   * ``cli``       — ``python -m repro.plan`` prebuild/inspect/verify.
 
-Read-through consumers: ``core.tpu_mapping.plan_gemm_tiling`` (hence
-``kernels.ops.gemm`` / ``kernels.goma_gemm``) and ``serving.Engine``
-(plan prewarming).  See DESIGN.md §Planner.
+Read-through consumers: ``core.tpu_mapping.plan_gemm_tiling`` and
+``plan_fused_mlp`` (hence ``kernels.ops.gemm`` / ``fused_mlp``), through
+the store a ``serving.Engine`` installs.  See DESIGN.md §Planner.
 """
-from .batch import (BatchPlanner, BatchReport,
-                    bucketed_serving_fused_chain_groups,
-                    bucketed_serving_plan_shape_groups,
-                    bucketed_serving_plan_shapes, cached_solve,
+from .batch import (BatchPlanner, BatchReport, cached_solve,
                     cached_solve_chain, cached_solve_pareto,
-                    flatten_shape_groups, prewarm_fused_plans,
-                    prewarm_pareto_plans, prewarm_tpu_plans,
-                    serving_plan_shapes, tile_plan_from_store)
+                    prewarm_fused_plans, prewarm_tpu_plans,
+                    tile_plan_from_store)
 from .manifest import ManifestEntry, ModelMappingManifest
 from .store import (ChainKey, FusedPlanEntry, ParetoKey, ParetoPlanEntry,
                     PlanEntry, PlanKey, PlanStore, chain_plan_key,
@@ -36,10 +32,8 @@ __all__ = [
     "BatchPlanner", "BatchReport", "ChainKey", "FusedPlanEntry",
     "ManifestEntry", "ModelMappingManifest", "ParetoKey", "ParetoPlanEntry",
     "PlanEntry", "PlanKey", "PlanStore",
-    "bucketed_serving_fused_chain_groups",
-    "bucketed_serving_plan_shape_groups", "bucketed_serving_plan_shapes",
     "cached_solve", "cached_solve_chain", "cached_solve_pareto",
-    "chain_plan_key", "flatten_shape_groups", "pareto_plan_key", "plan_key",
-    "prewarm_fused_plans", "prewarm_pareto_plans", "prewarm_tpu_plans",
-    "resolve_default_store", "serving_plan_shapes", "tile_plan_from_store",
+    "chain_plan_key", "pareto_plan_key", "plan_key",
+    "prewarm_fused_plans", "prewarm_tpu_plans",
+    "resolve_default_store", "tile_plan_from_store",
 ]

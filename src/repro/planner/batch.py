@@ -13,10 +13,10 @@ a populated plan store and a ``ModelMappingManifest``:
      ``core.solver.solve``);
   4. write every fresh solve back and emit the manifest artifact.
 
-Also hosts the read-through primitives consumed by ``core.tpu_mapping``
-and ``serving.Engine``: ``cached_solve`` (store-backed ``solve``),
-``prewarm_tpu_plans`` and ``tile_plan_from_store`` (manifest/store-driven
-Pallas tile plans with zero solver invocations).
+Also hosts the read-through primitives consumed by ``core.tpu_mapping``:
+``cached_solve`` (store-backed ``solve``), ``prewarm_tpu_plans`` and
+``tile_plan_from_store`` (manifest/store-driven Pallas tile plans with
+zero solver invocations).
 """
 from __future__ import annotations
 
@@ -513,199 +513,3 @@ def prewarm_fused_plans(chains: Iterable[tuple[int, int, int, int]],
         tpu_mapping.plan_fused_mlp(M, FF, K, N2, dtype_bytes=dtype_bytes)
         n += 1
     return n
-
-
-def prewarm_sharded_plans(shapes: Iterable[tuple[int, int, int]],
-                          store: PlanStore, *, n_chips: int,
-                          dtype_bytes: int = 2) -> int:
-    """Populate the store's sharded section with joint (mesh partition,
-    per-chip tiling) plans for the given logical (M, N, K) shapes on an
-    ``n_chips`` mesh; returns the number of shapes planned.
-
-    Shapes are planned under their *TPU dispatch identity* — the padded
-    GEMM and dtype-rescaled spec of ``tpu_mapping.tpu_problem`` — so the
-    mesh plan describes the same problem the Pallas tiling path solves,
-    and the padded dims (MXU multiples) keep small chip counts divisor-
-    feasible.  Each miss also leaves every enumerated sub-GEMM plan in
-    the store's single-chip section (see ``cached_solve_sharded``)."""
-    from ..core import tpu_mapping
-    n = 0
-    seen: set[tuple[int, int, int]] = set()
-    for (M, N, K) in shapes:
-        gemm, hw, padded = tpu_mapping.tpu_problem(M, N, K,
-                                                   dtype_bytes=dtype_bytes)
-        if padded in seen:
-            continue
-        seen.add(padded)
-        cached_solve_sharded(gemm, hw, n_chips, dtype_bytes=dtype_bytes,
-                             store=store)
-        n += 1
-    return n
-
-
-def prewarm_pareto_plans(shapes: Iterable[tuple[int, int, int]],
-                         store: PlanStore, *, dtype_bytes: int = 2,
-                         max_points: int | None = 24) -> int:
-    """Populate the store's pareto section with certified (energy, delay)
-    frontiers for the given logical (M, N, K) shapes under their TPU
-    dispatch identity (padded GEMM + dtype-rescaled spec, matching
-    ``prewarm_sharded_plans``); returns the number of shapes planned.
-
-    This is what a latency-SLO serving deployment runs ahead of traffic:
-    steady-state frontier-point selection then never invokes the solver
-    (``cached_solve_pareto`` hits rehydrate the whole frontier)."""
-    from ..core import tpu_mapping
-    n = 0
-    seen: set[tuple[int, int, int]] = set()
-    for (M, N, K) in shapes:
-        gemm, hw, padded = tpu_mapping.tpu_problem(M, N, K,
-                                                   dtype_bytes=dtype_bytes)
-        if padded in seen:
-            continue
-        seen.add(padded)
-        cached_solve_pareto(gemm, hw, store=store, max_points=max_points)
-        n += 1
-    return n
-
-
-def bucketed_serving_fused_chain_groups(
-        arch_id: str, *, slots: int, chunk_widths: Sequence[int],
-        cache_len: int,
-        cfg=None) -> dict[str, list[tuple[int, int, int, int]]]:
-    """Per-phase fused-MLP chain shapes (M, FF, K, N2) of a
-    continuous-batching deployment: one group per prefill-chunk width
-    plus the slot-batched decode group — the fused counterpart of
-    ``bucketed_serving_plan_shape_groups`` (same #widths + 1 bound).
-
-    ``cfg``: an explicit ``ArchConfig`` (e.g. the serving engine's own
-    model config, which may be a smoke variant) — chain dims then match
-    what the model's ``fused_mlp`` dispatch will actually request;
-    default resolves ``arch_id`` from the registry."""
-    from ..core.workloads import arch_decode_chains, config_decode_chains
-
-    def rows(batch):
-        chains = (config_decode_chains(cfg, batch=batch) if cfg is not None
-                  else arch_decode_chains(arch_id, batch=batch,
-                                          cache_len=cache_len))
-        out = []
-        for _, chain, _ in chains:
-            dims = (chain.M, chain.inter_width, chain.producer.Lz,
-                    chain.consumer.Ly)
-            if dims not in out:
-                out.append(dims)
-        return out
-
-    groups = {f"chunk{w}": rows(w) for w in chunk_widths}
-    groups["decode"] = rows(slots)
-    return groups
-
-
-def serving_plan_shapes(arch_id: str, *, batch: int, prompt_len: int,
-                        cache_len: int) -> list[tuple[int, int, int]]:
-    """Distinct GEMM (M, N, K) shapes a serving deployment will hit:
-    the prefill extraction at prompt_len plus batched decode steps."""
-    from ..core.workloads import arch_decode_gemms, arch_gemms
-    shapes: list[tuple[int, int, int]] = []
-    seen = set()
-    rows = (arch_gemms(arch_id, seq=prompt_len, batch=batch)
-            + arch_decode_gemms(arch_id, batch=batch, cache_len=cache_len))
-    for _, gemm, _ in rows:
-        if gemm.dims not in seen:
-            seen.add(gemm.dims)
-            shapes.append(gemm.dims)
-    return shapes
-
-
-def bucketed_serving_plan_shape_groups(
-        arch_id: str, *, slots: int, chunk_widths: Sequence[int],
-        cache_len: int) -> dict[str, list[tuple[int, int, int]]]:
-    """Per-phase GEMM (M, N, K) shape groups of a continuous-batching
-    deployment (serving.sched): one group per prefill-chunk width plus
-    the slot-batched decode group.
-
-    A prefill chunk of width W on one sequence flattens to exactly the
-    GEMM set of a batch-W decode step against the same static cache —
-    M = W token rows for every projection, attention score/context
-    against cache_len — so both phases extract through
-    ``arch_decode_gemms`` and the total plan-key count is bounded by
-    #chunk_widths + 1, independent of traffic.
-    """
-    from ..core.workloads import arch_decode_gemms
-
-    def dedup(rows):
-        out, seen = [], set()
-        for _, gemm, _ in rows:
-            if gemm.dims not in seen:
-                seen.add(gemm.dims)
-                out.append(gemm.dims)
-        return out
-
-    groups = {
-        f"chunk{w}": dedup(arch_decode_gemms(arch_id, batch=w,
-                                             cache_len=cache_len))
-        for w in chunk_widths}
-    groups["decode"] = dedup(arch_decode_gemms(arch_id, batch=slots,
-                                               cache_len=cache_len))
-    return groups
-
-
-def bucketed_spec_plan_shape_groups(
-        arch_id: str, *, batch: int, spec_widths: Sequence[int],
-        cache_len: int,
-        draft_arch_id: str | None = None
-        ) -> dict[str, list[tuple[int, int, int]]]:
-    """Per-width GEMM shape groups of a speculative-decoding deployment
-    (serving.router.spec), from the hand-enumerated extraction tables:
-    a batched verify step over a draft window of width W flattens to
-    exactly the GEMM set of a batch ``batch * W`` decode step (every
-    projection sees batch*W token rows; attention runs per row against
-    the same static cache), so each ``verify{W}`` group extracts through
-    ``arch_decode_gemms`` like the prefill-chunk groups do.  With a
-    draft model (``draft_arch_id``), its width-1 decode and catch-up
-    chunk programs join the group dict under ``draft.*`` — the
-    enumerated counterpart of
-    ``capture.plan.captured_spec_plan_shape_groups``."""
-    from ..core.workloads import arch_decode_gemms
-
-    def dedup(rows):
-        out, seen = [], set()
-        for _, gemm, _ in rows:
-            if gemm.dims not in seen:
-                seen.add(gemm.dims)
-                out.append(gemm.dims)
-        return out
-
-    groups = {
-        f"verify{w}": dedup(arch_decode_gemms(arch_id, batch=batch * w,
-                                              cache_len=cache_len))
-        for w in spec_widths}
-    if draft_arch_id is not None:
-        groups["draft.decode"] = dedup(arch_decode_gemms(
-            draft_arch_id, batch=1, cache_len=cache_len))
-        for w in spec_widths:
-            groups[f"draft.chunk{w}"] = dedup(arch_decode_gemms(
-                draft_arch_id, batch=w, cache_len=cache_len))
-    return groups
-
-
-def flatten_shape_groups(
-        groups: dict[str, list[tuple[int, int, int]]]
-        ) -> list[tuple[int, int, int]]:
-    """Deduplicated union of per-phase shape groups, first-seen order."""
-    shapes, seen = [], set()
-    for group in groups.values():
-        for dims in group:
-            if dims not in seen:
-                seen.add(dims)
-                shapes.append(dims)
-    return shapes
-
-
-def bucketed_serving_plan_shapes(
-        arch_id: str, *, slots: int, chunk_widths: Sequence[int],
-        cache_len: int) -> list[tuple[int, int, int]]:
-    """Flat deduplicated union of ``bucketed_serving_plan_shape_groups``
-    — the prewarm set for a continuous-batching scheduler."""
-    return flatten_shape_groups(bucketed_serving_plan_shape_groups(
-        arch_id, slots=slots, chunk_widths=chunk_widths,
-        cache_len=cache_len))

@@ -20,18 +20,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import logging
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import tpu_mapping
 from ..models.model import Model
-from ..obs.registry import get_registry
 from ..obs.tracing import count_compiles
-
-_REG = get_registry()
-_LOG = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -86,9 +82,13 @@ class Engine:
         self.model = model
         self.params = params
         self.cfg = cfg
-        # optional GOMA plan database (repro.planner.PlanStore): serving
-        # traffic consumes cached kernel tilings instead of solving inline
-        self.plan_store = plan_store
+        # optional GOMA plan database (repro.planner.PlanStore),
+        # installed for dispatch: a kernel traced into one of this
+        # engine's programs resolves its tiling through it
+        # (core.tpu_mapping), and jit caching keeps steady state free of
+        # solves
+        if plan_store is not None:
+            tpu_mapping.set_plan_store(plan_store)
         count_compiles()
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, max_len=cfg.cache_len))
@@ -177,209 +177,6 @@ class Engine:
     def sample(self, logits, rng):
         """Greedy/temperature sampling (row-wise; rng may be None)."""
         return self._sample(logits, rng)
-
-    def prewarm_plans(self, arch_id: str | None, batch: int,
-                      prompt_len: int, *,
-                      dtype_bytes: int | None = None,
-                      source: str = "capture") -> int:
-        """Pre-plan every GEMM tiling this deployment will hit (prefill at
-        prompt_len + batched decode against the KV cache), through the
-        plan database when one is installed.  After this, the serving loop
-        never invokes the GOMA solver: every `kernels.ops.gemm` dispatch
-        resolves its TpuTilePlan from cache.  Returns #shapes planned.
-
-        ``source="capture"`` (default) reads the shape set off the
-        engine's *own* jaxpr-traced prefill/decode programs
-        (capture.plan) — the plans match what this model actually
-        dispatches, smoke variants and frontend prefixes included, and
-        ``arch_id`` is only documentation.  ``source="enumerated"``
-        falls back to the hand-enumerated ``arch_id`` extraction tables.
-
-        dtype_bytes defaults to the model's compute dtype — plan identity
-        includes the dtype-rescaled VMEM capacity, so prewarming bf16
-        plans for an f32 engine would all miss at dispatch time."""
-        if source == "capture":
-            from ..capture.plan import serving_capture_shapes
-            shapes = serving_capture_shapes(self.model, batch, prompt_len,
-                                            self.cfg.cache_len)
-        else:
-            if arch_id is None:
-                raise ValueError(
-                    "prewarm_plans(source='enumerated') needs an arch_id "
-                    "to look up the extraction tables; only the capture "
-                    "source reads everything off the model itself")
-            from ..planner.batch import serving_plan_shapes
-            shapes = serving_plan_shapes(arch_id, batch=batch,
-                                         prompt_len=prompt_len,
-                                         cache_len=self.cfg.cache_len)
-        return self.prewarm_shapes(shapes, dtype_bytes=dtype_bytes)
-
-    def prewarm_shapes(self, shapes, *,
-                       dtype_bytes: int | None = None) -> int:
-        """Plan an explicit (M, N, K) shape list through the installed
-        store (or the in-process cache when none is).  Shared by
-        ``prewarm_plans`` and the scheduler's bucketed prewarm.
-
-        Best-effort: one unplannable shape is logged, counted under
-        ``sched.prewarm_failures`` and skipped — it will solve cold at
-        first dispatch instead of failing the whole prewarm.  Returns
-        #shapes actually planned."""
-        from ..planner.batch import prewarm_tpu_plans
-        from ..planner.store import resolve_default_store
-        if dtype_bytes is None:
-            dtype_bytes = self.dispatch_dtype_bytes
-        shapes = list(shapes)
-        store = (self.plan_store if self.plan_store is not None
-                 else resolve_default_store())
-        planned = 0
-        for s in shapes:
-            try:
-                if store is None:
-                    from ..core.tpu_mapping import plan_gemm_tiling
-                    plan_gemm_tiling(*s, dtype_bytes=dtype_bytes)
-                    planned += 1
-                else:
-                    planned += prewarm_tpu_plans(
-                        [s], store, dtype_bytes=dtype_bytes)
-            except Exception as e:
-                _REG.inc("sched.prewarm_failures")
-                _LOG.warning("prewarm failed for GEMM shape %s (%s: %s); "
-                             "it will solve at dispatch", s,
-                             type(e).__name__, e)
-        return planned
-
-    def prewarm_chains(self, chains, *,
-                       dtype_bytes: int | None = None) -> int:
-        """Plan an explicit fused-MLP chain list ((M, FF, K, N2) shapes)
-        through the installed store's fused section (or the in-process
-        cache).  The fused counterpart of ``prewarm_shapes``: after this,
-        a ``fused_mlp``-routed model resolves every chain plan from
-        cache — zero chain solves in steady state.  Best-effort, like
-        ``prewarm_shapes``."""
-        from ..planner.batch import prewarm_fused_plans
-        from ..planner.store import resolve_default_store
-        if dtype_bytes is None:
-            dtype_bytes = self.dispatch_dtype_bytes
-        chains = list(chains)
-        store = (self.plan_store if self.plan_store is not None
-                 else resolve_default_store())
-        planned = 0
-        for c in chains:
-            try:
-                if store is None:
-                    from ..core.tpu_mapping import plan_fused_mlp
-                    plan_fused_mlp(*c, dtype_bytes=dtype_bytes)
-                    planned += 1
-                else:
-                    planned += prewarm_fused_plans(
-                        [c], store, dtype_bytes=dtype_bytes)
-            except Exception as e:
-                _REG.inc("sched.prewarm_failures")
-                _LOG.warning("prewarm failed for fused chain %s (%s: %s); "
-                             "it will solve at dispatch", c,
-                             type(e).__name__, e)
-        return planned
-
-    def prewarm_sharded_shapes(self, shapes, *, n_chips: int,
-                               dtype_bytes: int | None = None) -> int:
-        """Plan an explicit (M, N, K) shape list through the installed
-        store's *sharded* section: each shape gets a joint (mesh
-        partition, per-chip tiling) plan for an ``n_chips`` mesh (see
-        dist.mesh_solve).  The mesh counterpart of ``prewarm_shapes``;
-        after this, a sharded deployment resolves every partition +
-        tiling decision from cache — zero joint solves in steady state.
-
-        Requires a store (sharded plans are deployment artifacts, not
-        in-process caches): with none installed this is a counted no-op.
-        Best-effort per shape, like ``prewarm_shapes``; failures count
-        under ``dist.prewarm_failures``.  Returns #shapes planned."""
-        from ..planner.batch import prewarm_sharded_plans
-        from ..planner.store import resolve_default_store
-        if dtype_bytes is None:
-            dtype_bytes = self.dispatch_dtype_bytes
-        store = (self.plan_store if self.plan_store is not None
-                 else resolve_default_store())
-        if store is None:
-            _LOG.warning("prewarm_sharded_shapes needs a plan store; "
-                         "skipping (install one via Engine(plan_store=...) "
-                         "or $GOMA_PLAN_DB)")
-            _REG.inc("dist.prewarm_skipped")
-            return 0
-        planned = 0
-        for s in list(shapes):
-            try:
-                planned += prewarm_sharded_plans(
-                    [s], store, n_chips=n_chips, dtype_bytes=dtype_bytes)
-            except Exception as e:
-                _REG.inc("dist.prewarm_failures")
-                _LOG.warning("sharded prewarm failed for GEMM shape %s "
-                             "(%s: %s); it will co-solve at first use", s,
-                             type(e).__name__, e)
-        _REG.inc("dist.prewarmed", planned)
-        return planned
-
-    def prewarm_pareto_shapes(self, shapes, *,
-                              dtype_bytes: int | None = None,
-                              max_points: int | None = 24) -> int:
-        """Build certified (energy, delay) frontiers for an explicit
-        (M, N, K) shape list into the installed store's pareto section
-        (under the TPU dispatch identity).  The frontier counterpart of
-        ``prewarm_shapes``: after this, latency-SLO point selection
-        (``pareto_frontier`` + ``core.pareto.select_frontier_point``)
-        never invokes the solver.
-
-        Requires a store (frontiers are deployment artifacts): with none
-        installed this is a counted no-op.  Best-effort per shape;
-        failures count under ``sched.prewarm_failures``."""
-        from ..planner.batch import prewarm_pareto_plans
-        from ..planner.store import resolve_default_store
-        if dtype_bytes is None:
-            dtype_bytes = self.dispatch_dtype_bytes
-        store = (self.plan_store if self.plan_store is not None
-                 else resolve_default_store())
-        if store is None:
-            _LOG.warning("prewarm_pareto_shapes needs a plan store; "
-                         "skipping (install one via Engine(plan_store=...) "
-                         "or $GOMA_PLAN_DB)")
-            _REG.inc("pareto.prewarm_skipped")
-            return 0
-        planned = 0
-        for s in list(shapes):
-            try:
-                planned += prewarm_pareto_plans(
-                    [s], store, dtype_bytes=dtype_bytes,
-                    max_points=max_points)
-            except Exception as e:
-                _REG.inc("sched.prewarm_failures")
-                _LOG.warning("pareto prewarm failed for GEMM shape %s "
-                             "(%s: %s); skipping", s, type(e).__name__, e)
-        _REG.inc("pareto.prewarmed", planned)
-        return planned
-
-    def pareto_frontier(self, M: int, N: int, K: int, *,
-                        dtype_bytes: int | None = None,
-                        max_points: int | None = 24):
-        """The certified (energy, delay) frontier of one GEMM under its
-        TPU dispatch identity, read through the installed store
-        (``planner.batch.cached_solve_pareto``); a hit rehydrates the
-        whole frontier with zero solver invocations."""
-        from ..core import tpu_mapping
-        from ..planner.batch import cached_solve_pareto
-        from ..planner.store import resolve_default_store
-        if dtype_bytes is None:
-            dtype_bytes = self.dispatch_dtype_bytes
-        gemm, hw, _ = tpu_mapping.tpu_problem(M, N, K,
-                                              dtype_bytes=dtype_bytes)
-        store = (self.plan_store if self.plan_store is not None
-                 else resolve_default_store())
-        return cached_solve_pareto(gemm, hw, store=store,
-                                   max_points=max_points)
-
-    @property
-    def dispatch_dtype_bytes(self) -> int:
-        """The dtype under which this engine's GEMMs dispatch (plan
-        identity includes the dtype-rescaled VMEM capacity)."""
-        return jnp.dtype(self.model.cfg.compute_dtype).itemsize
 
     def validate_capacity(self, prompt_len: int, max_new_tokens: int, *,
                           prefix_len: int = 0, lookahead: int = 0) -> None:

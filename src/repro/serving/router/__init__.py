@@ -1,5 +1,5 @@
 """Serving scale-out: replica routing, KV prefix reuse, speculative
-decoding — all on plan-prewarmed paths (DESIGN.md §Scale-out)."""
+decoding (DESIGN.md §Scale-out)."""
 from .prefix import PrefixCache
 from .router import ReplicaRouter, RouterConfig
 from .spec import (DEFAULT_WIDTHS, ModelDrafter, NgramDrafter,

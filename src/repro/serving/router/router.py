@@ -10,13 +10,6 @@ interleaves on one machine while the virtual timeline is what N
 parallel chips would have seen.  Fleet throughput is total tokens over
 the *makespan* (the slowest clock), not the summed busy time.
 
-Plan prewarm is one pass for the whole fleet: replica 0 derives the
-bucketed GEMM groups and pushes every tiling through the plan store /
-in-process cache; replicas 1..N-1 are constructed with the donor's
-group dicts and skip both derivation and planning (``plan_groups``
-ctor kwarg).  Steady state across *all* replicas certifies zero solver
-invocations, same as a single scheduler.
-
 Routing is least-loaded: queued + in-flight requests, ties broken by
 the laggiest clock.  An optional shared ``PrefixCache`` rides across
 replicas (KV rows are replica-agnostic), so a prefix prefilled on one
@@ -65,8 +58,8 @@ class RouterConfig:
 
 class ReplicaRouter:
     def __init__(self, engine: Engine, cfg: RouterConfig | None = None, *,
-                 arch_id: str | None = None, prefix_cache=None,
-                 drafter=None, on_token=None, on_finish=None):
+                 prefix_cache=None, drafter=None, on_token=None,
+                 on_finish=None):
         self.engine = engine
         self.cfg = cfg or RouterConfig()
         n = self.cfg.replicas
@@ -87,23 +80,12 @@ class ReplicaRouter:
             _LOG.warning("router: continuous batching unavailable (%s); "
                          "serving via static Engine.generate", e)
             return
-        # replica 0 is the prewarm donor: one derivation + one planning
-        # pass covers the fleet (identical engine/config -> identical
-        # bucketed shape groups on every replica)
-        donor = ContinuousScheduler(
-            engine, self.cfg.sched, arch_id=arch_id,
-            clock=self.clocks[0].now, prefix_cache=prefix_cache,
-            drafter=drafter, on_token=on_token, on_finish=on_finish)
-        self.scheds.append(donor)
-        for i in range(1, n):
+        for i in range(n):
             self.scheds.append(ContinuousScheduler(
                 engine, self.cfg.sched, clock=self.clocks[i].now,
                 prefix_cache=prefix_cache, drafter=drafter,
-                on_token=on_token, on_finish=on_finish,
-                plan_groups=donor._plan_groups,
-                chain_groups=donor._chain_groups))
+                on_token=on_token, on_finish=on_finish))
         self.alive = [True] * n
-        self.prewarmed_plans = donor.prewarmed_plans
         _REG.set_gauge("router.replicas", n)
 
     # ------------------------------------------------------------ routing

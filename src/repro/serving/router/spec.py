@@ -18,15 +18,13 @@ Two drafters:
   is high whenever generation revisits its own context (repetitive or
   cyclic text, copy-heavy spans) and harmless when it doesn't.
 - ``ModelDrafter`` — a small draft model served through its own
-  ``Engine`` (capture-prewarmed like the target, so the draft GEMMs
-  also hit the plan store with zero steady-state solves).  It keeps a
-  single-stream KV cache teacher-forced to the committed context:
-  per ``propose`` it catches up on the tokens committed since its last
-  call (rejected drafts are overwritten in place — stale positions are
-  masked, the same invariant the target's verify step relies on), then
-  free-runs k greedy tokens.  Single-stream by design: use it with
-  ``spec_generate`` or a slots=1 scheduler; multi-slot scheduling wants
-  the stateless ``NgramDrafter``.
+  ``Engine``.  It keeps a single-stream KV cache teacher-forced to the
+  committed context: per ``propose`` it catches up on the tokens
+  committed since its last call (rejected drafts are overwritten in
+  place — stale positions are masked, the same invariant the target's
+  verify step relies on), then free-runs k greedy tokens.  Single-stream
+  by design: use it with ``spec_generate`` or a slots=1 scheduler;
+  multi-slot scheduling wants the stateless ``NgramDrafter``.
 
 ``spec_generate`` is the static-path loop (the ``Engine.generate``
 counterpart): one stream, greedy only, with an adaptive verify-window
@@ -55,8 +53,6 @@ class NgramDrafter:
     request's own (prompt + generated) context, most recent match wins,
     proposal = the tokens that followed it, padded with the last
     proposal when the match runs out.  No model, no state."""
-
-    model = None                     # no draft model to prewarm
 
     def __init__(self, n: int = 3):
         if n < 1:
@@ -94,7 +90,6 @@ class ModelDrafter:
 
     def __init__(self, engine: Engine):
         self.engine = engine
-        self.model = engine.model    # exposed for capture-prewarm
         self._cache = None
         self._ctx: list[int] = []    # tokens the cache is committed to
 

@@ -18,10 +18,10 @@ join a running batch without draining it:
      and is evicted on its stop token or token budget.
 
 Every jitted program the loop touches has a traffic-independent shape
-(slot count × chunk buckets), and with a plan store installed the same
-bucketing bounds the GEMM plan-key set — prewarmed at construction, so
-steady-state traffic resolves every kernel tiling with zero solver
-invocations (asserted via store/solver counters in the tests).
+(slot count × chunk buckets).  The scheduler plans nothing: a GOMA
+kernel in one of those programs resolves its tiling when the program is
+traced (``core.tpu_mapping``, through the engine's plan store), and jit
+caching keeps steady-state traffic free of solver invocations.
 
 Outputs are token-identical to running each request alone through the
 static ``Engine.generate`` oracle: chunk padding is causally masked,
@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import logging
 import time
 from typing import Callable
 
@@ -52,7 +51,6 @@ from .requests import Request, RequestResult, RequestState
 from .slots import Slot, SlotManager
 
 _REG = get_registry()
-_LOG = logging.getLogger(__name__)
 
 # Families whose cache is a pure per-layer KV tensor with batch on axis 1
 # (slot grafting + slot-indexed writes assume that layout).  Recurrent
@@ -94,30 +92,6 @@ class SchedConfig:
     temperature: float = 0.0
     stop_token: int | None = None       # default; requests may override
     rng_seed: int = 0                   # per-request sampling keys
-    resolve_plans: bool = True          # resolve tile plans per tick when a
-    #                                     plan store is installed
-    prewarm_source: str = "capture"     # "capture": read the per-bucket
-    #                                     GEMM groups off the engine
-    #                                     model's own jaxpr-traced
-    #                                     decode-step programs;
-    #                                     "enumerated": the hand
-    #                                     extraction tables (arch_id)
-    mesh_chips: int = 1                 # > 1: this deployment runs on an
-    #                                     n-chip mesh — prewarm also
-    #                                     populates the store's sharded
-    #                                     section with joint (partition,
-    #                                     tiling) plans for every bucketed
-    #                                     GEMM shape (dist.mesh_solve)
-    latency_slo_ns: float | None = None  # per-GEMM latency SLO: prewarm
-    #                                     also builds the certified
-    #                                     (energy, delay) frontier of
-    #                                     every bucketed shape and picks
-    #                                     the cheapest point meeting the
-    #                                     SLO (core.pareto.
-    #                                     select_frontier_point) into
-    #                                     ``slo_points``; None keeps the
-    #                                     energy-optimal plan (existing
-    #                                     behavior, byte-for-byte)
     # --- degradation knobs (DESIGN.md §Resilience) ---
     shed_on_full: bool = False          # queue full: return a terminal
     #                                     REJECTED result instead of
@@ -164,9 +138,7 @@ class ContinuousScheduler:
                  on_tick: Callable[["ContinuousScheduler"], None]
                  | None = None,
                  clock: Callable[[], float] | None = None,
-                 prefix_cache=None, drafter=None,
-                 plan_groups: dict | None = None,
-                 chain_groups: dict | None = None):
+                 prefix_cache=None, drafter=None):
         ensure_supported_family(engine.model.cfg)
         self.engine = engine
         self.cfg = cfg
@@ -217,188 +189,8 @@ class ContinuousScheduler:
         self._cur = np.zeros((cfg.slots,), np.int32)
         self._pos = np.zeros((cfg.slots,), np.int32)
         self.slot_cache = engine.new_cache(cfg.slots)
-        # plan-store integration: prewarm every bucketed GEMM tiling now
-        # so steady-state traffic never invokes the solver
+        # a label only: nothing reads it (chipbench's harness passes it)
         self.arch_id = arch_id
-        self._plan_groups: dict[str, list[tuple[int, int, int]]] = {}
-        self._chain_groups: dict[str, list[tuple[int, int, int, int]]] = {}
-        self._resolved_groups: set[str] = set()
-        self.prewarmed_plans = 0
-        self.prewarmed_chains = 0
-        self.prewarmed_sharded = 0
-        self.prewarmed_pareto = 0
-        # SLO-selected frontier point per bucketed GEMM shape (filled at
-        # prewarm when cfg.latency_slo_ns is set; selection is fixed at
-        # construction, so steady-state traffic never re-solves).  On the
-        # TPU dispatch spec the spatial array is fixed, so the frontier
-        # is single-point and the selected mapping IS the energy-optimal
-        # one — token streams and stored plan identities are unchanged.
-        self.slo_points: dict[tuple[int, int, int], object] = {}
-        # capture-source prewarm reads everything off the engine's own
-        # model, so a plan-store deployment prewarms even without an
-        # arch_id; enumerated prewarm needs the arch extraction tables.
-        # A replica constructed with explicit ``plan_groups`` (the
-        # router's shared one-pass prewarm) skips both derivation and
-        # planning: the donor replica already pushed every group through
-        # the store / in-process plan cache, so this replica only needs
-        # the group dict for its per-phase ``_resolve_plans`` calls.
-        if plan_groups is not None:
-            self._plan_groups = dict(plan_groups)
-            self._chain_groups = dict(chain_groups or {})
-        elif arch_id is not None or (cfg.prewarm_source == "capture"
-                                     and engine.plan_store is not None):
-            self.prewarmed_plans = self._prewarm(arch_id)
-
-    # ------------------------------------------------------------ plan DB
-    def _prewarm(self, arch_id: str) -> int:
-        """Best-effort bucketed prewarm: any one group failing to plan
-        must not take down scheduler construction — the serving loop
-        still works (those shapes just solve cold at first dispatch),
-        so each failure is logged, counted (``sched.prewarm_failures``)
-        and skipped."""
-        from ...planner.batch import (bucketed_serving_fused_chain_groups,
-                                      bucketed_serving_plan_shape_groups)
-        if getattr(self.engine.model.cfg, "fused_mlp", False):
-            # a fused-MLP model dispatches one chain plan per bucket
-            # group instead of the per-GEMM gate/up/down tilings; the
-            # same #widths+1 bound applies (DESIGN.md §Fusion).  Chains
-            # derive from the engine's *own* model config so prewarm
-            # matches dispatch even for smoke/reduced variants — and
-            # chains go first so a capture-mode trace below resolves
-            # its fused-kernel plans from the warm cache.
-            try:
-                self._chain_groups = bucketed_serving_fused_chain_groups(
-                    arch_id, slots=self.cfg.slots,
-                    chunk_widths=self.buckets.chunk_widths,
-                    cache_len=self.engine.cfg.cache_len,
-                    cfg=self.engine.model.cfg)
-            except Exception as e:
-                self._chain_groups = {}
-                _REG.inc("sched.prewarm_failures")
-                _LOG.warning("fused chain-group derivation failed "
-                             "(%s: %s); chains will solve at dispatch",
-                             type(e).__name__, e)
-            seen_chains: set[tuple[int, ...]] = set()
-            for group, chains in self._chain_groups.items():
-                fresh_chains = [c for c in chains
-                                if c not in seen_chains]
-                seen_chains.update(fresh_chains)
-                if not fresh_chains:
-                    continue
-                try:
-                    self.prewarmed_chains += \
-                        self.engine.prewarm_chains(fresh_chains)
-                except Exception as e:
-                    _REG.inc("sched.prewarm_failures")
-                    _LOG.warning("chain prewarm failed for group %r "
-                                 "(%s: %s); continuing", group,
-                                 type(e).__name__, e)
-        try:
-            if self.cfg.prewarm_source == "capture":
-                # per-bucket GEMM groups read off the engine model's own
-                # jaxpr-traced decode-step programs (chunked-prefill
-                # continuations at each width + the slot-batched decode):
-                # prewarmed plans match actual dispatch by construction
-                from ...capture.plan import \
-                    captured_serving_plan_shape_groups
-                self._plan_groups = captured_serving_plan_shape_groups(
-                    self.engine.model, slots=self.cfg.slots,
-                    chunk_widths=self.buckets.chunk_widths,
-                    cache_len=self.engine.cfg.cache_len)
-            else:
-                self._plan_groups = bucketed_serving_plan_shape_groups(
-                    arch_id, slots=self.cfg.slots,
-                    chunk_widths=self.buckets.chunk_widths,
-                    cache_len=self.engine.cfg.cache_len)
-        except Exception as e:
-            self._plan_groups = {}
-            _REG.inc("sched.prewarm_failures")
-            _LOG.warning("plan-group derivation failed (%s: %s); GEMMs "
-                         "will solve at dispatch", type(e).__name__, e)
-        if self.drafter is not None and self.cfg.spec_width is not None:
-            # speculative decoding dispatches the batched verify program
-            # (and, with a model drafter, the draft model's own decode
-            # programs) — same bounded-group treatment as the chunk
-            # widths, same best-effort failure policy
-            try:
-                from ...capture.plan import captured_spec_plan_shape_groups
-                self._plan_groups.update(captured_spec_plan_shape_groups(
-                    self.engine.model, batch=self.cfg.slots,
-                    cache_len=self.engine.cfg.cache_len,
-                    spec_widths=(self.cfg.spec_width,),
-                    draft_model=getattr(self.drafter, "model", None)))
-            except Exception as e:
-                _REG.inc("sched.prewarm_failures")
-                _LOG.warning("spec plan-group derivation failed (%s: %s)"
-                             "; verify GEMMs will solve at dispatch",
-                             type(e).__name__, e)
-        planned = 0
-        seen: set[tuple[int, int, int]] = set()
-        for group, shapes in self._plan_groups.items():
-            fresh = [s for s in shapes if s not in seen]
-            seen.update(fresh)
-            if not fresh:
-                continue
-            try:
-                planned += self.engine.prewarm_shapes(fresh)
-            except Exception as e:
-                _REG.inc("sched.prewarm_failures")
-                _LOG.warning("plan prewarm failed for group %r (%s: %s); "
-                             "continuing", group, type(e).__name__, e)
-        if self.cfg.mesh_chips > 1 and self.engine.plan_store is not None:
-            # mesh deployment: the same deduped shape union also gets
-            # joint (mesh partition, per-chip tiling) plans in the
-            # store's sharded section — steady state then resolves both
-            # the partition and the per-chip tiling from cache
-            try:
-                self.prewarmed_sharded = self.engine.prewarm_sharded_shapes(
-                    sorted(seen), n_chips=self.cfg.mesh_chips)
-            except Exception as e:
-                _REG.inc("sched.prewarm_failures")
-                _LOG.warning("sharded prewarm failed (%s: %s); partitions "
-                             "will co-solve at first use",
-                             type(e).__name__, e)
-        if self.cfg.latency_slo_ns is not None:
-            # latency-SLO deployment: build every bucketed shape's
-            # certified (energy, delay) frontier and fix the per-shape
-            # point selection now — steady state then makes zero solver
-            # invocations (frontiers rehydrate from the store's pareto
-            # section).  Best-effort like the rest of prewarm.
-            from ...core.pareto import select_frontier_point
-            if self.engine.plan_store is not None:
-                self.prewarmed_pareto = self.engine.prewarm_pareto_shapes(
-                    sorted(seen))
-            for s in sorted(seen):
-                try:
-                    res = self.engine.pareto_frontier(*s)
-                    p = select_frontier_point(res.points,
-                                              self.cfg.latency_slo_ns)
-                except Exception as e:
-                    _REG.inc("sched.prewarm_failures")
-                    _LOG.warning("frontier selection failed for %s "
-                                 "(%s: %s); energy-optimal plan kept",
-                                 s, type(e).__name__, e)
-                    continue
-                if p is not None:
-                    self.slo_points[s] = p
-            _REG.inc("sched.slo_points", len(self.slo_points))
-        return planned
-
-    def _resolve_plans(self, group: str) -> None:
-        """Resolve the tile plans one phase dispatches, once per group
-        (first dispatch).  After the constructor's prewarm these are all
-        in-process cache hits — the zero-solve steady state."""
-        if group in self._resolved_groups or \
-                not (self.cfg.resolve_plans and self._plan_groups):
-            return
-        from ...core.tpu_mapping import plan_fused_mlp, plan_gemm_tiling
-        for (M, N, K) in self._plan_groups.get(group, ()):
-            plan_gemm_tiling(M, N, K,
-                             dtype_bytes=self.engine.dispatch_dtype_bytes)
-        for (M, FF, K, N2) in self._chain_groups.get(group, ()):
-            plan_fused_mlp(M, FF, K, N2,
-                           dtype_bytes=self.engine.dispatch_dtype_bytes)
-        self._resolved_groups.add(group)
 
     # ---------------------------------------------------------- admission
     def submit(self, req: Request) -> RequestResult | None:
@@ -547,7 +339,6 @@ class ContinuousScheduler:
                 with _span("sched.graft", tick=tick):
                     self._activate(self._prefill, logits, chunk)
                 self._prefill = None
-            self._resolve_plans(f"chunk{chunk.width}")
 
         # 3. slot-indexed decode over the whole pool
         active = [s for s in self.slots.busy()
@@ -589,7 +380,6 @@ class ContinuousScheduler:
             _REG.inc("sched.decode_steps")
             _REG.inc("sched.padded_decode_rows",
                      len(self.slots) - len(active))
-            self._resolve_plans("decode")
 
         padded_rows = len(self.slots) - len(active) if decoded else 0
         if tick_sp:
@@ -700,7 +490,6 @@ class ContinuousScheduler:
         _REG.inc("sched.decode_steps")
         _REG.inc("sched.padded_decode_rows",
                  len(self.slots) - len(active))
-        self._resolve_plans(f"verify{w}")
         return active
 
     # ------------------------------------------------------ fault isolation

@@ -1,4 +1,4 @@
-"""Program capture: jaxpr harvest, PlanProgram IR, plan pass, serving.
+"""Program capture: jaxpr harvest, PlanProgram IR, plan pass.
 
 The headline guarantee is the differential oracle: capturing the
 LlmSpec reference programs (capture.reference) reproduces the
@@ -321,31 +321,6 @@ def test_batch_planner_solves_each_unique_shape_once():
     assert solver_stats()["calls"] == len(unique_dims)
     assert planner.last_report.unique_gemms == len(unique_dims)
     assert len(entries) == len(unique_dims)
-
-
-# ------------------------------------------------ serving integration
-
-def test_engine_prewarm_routes_through_capture(tmp_path):
-    from repro.configs import get_config
-    from repro.core import tpu_mapping
-    from repro.models.model import build_model
-    from repro.planner.store import PlanStore
-    from repro.serving import Engine, ServeConfig
-    cfg = get_config("llama3-8b", smoke=True)
-    model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
-    store = PlanStore(tmp_path)
-    engine = Engine(model, params, ServeConfig(cache_len=32),
-                    plan_store=store)
-    try:
-        n = engine.prewarm_plans(None, 1, 8)      # captured: no arch_id
-        from repro.capture import serving_capture_shapes
-        shapes = serving_capture_shapes(model, 1, 8, 32)
-        assert n == len(shapes) > 0
-        assert len(store) > 0
-    finally:
-        engine.plan_store = None
-        tpu_mapping.set_plan_store(None)
 
 
 # ------------------------------------------------ CLI

@@ -339,36 +339,75 @@ def _oracle_tokens(oracle, req) -> list[int]:
             oracle.generate(req.tokens[None])[0][:req.max_new_tokens]]
 
 
-def test_store_faults_keep_tokens_identical(serving, tmp_path):
+# Store chaos runs the fused-MLP smoke model, whose kernels read their
+# chain plans through the plan store when traced.  A prefill chunk wider
+# than the MXU (padded M 256) and the 2-slot decode (padded M 128)
+# dispatch two distinct chains: two store reads, one per store fault.
+FUSED_CACHE = 176
+FUSED_WIDTHS = (8, 160)
+
+
+@pytest.fixture(scope="module")
+def fused_serving():
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving import Engine, ServeConfig
+    cfg = dataclasses.replace(get_config("llama3-8b", smoke=True),
+                              fused_mlp=True)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    oracle = Engine(model, params,
+                    ServeConfig(max_new_tokens=10, cache_len=FUSED_CACHE))
+    return cfg, model, params, oracle
+
+
+def _fill_store_then_drop_caches(model, params, root, reqs) -> None:
+    """Serve ``reqs`` fault-free over a store at ``root``, then drop
+    every warm cache — the plan caches and the traced programs — so the
+    next serve reads its plans back off disk."""
+    import jax
+
+    from repro.core import tpu_mapping
+    from repro.serving import Engine, ServeConfig
+    from repro.serving.sched import ContinuousScheduler, SchedConfig
+    jax.clear_caches()
+    engine = Engine(model, params,
+                    ServeConfig(max_new_tokens=10, cache_len=FUSED_CACHE),
+                    plan_store=PlanStore(root))
+    ContinuousScheduler(
+        engine, SchedConfig(slots=2, chunk_widths=FUSED_WIDTHS)).run(reqs)
+    tpu_mapping.set_plan_store(None)
+    tpu_mapping.plan_gemm_tiling.cache_clear()
+    jax.clear_caches()
+
+
+def test_store_faults_keep_tokens_identical(fused_serving, tmp_path):
     """Injected store read faults + corruption during a plan-store
     serving run: cold re-solves fill the gaps and every served request
     stays token-identical to the fault-free oracle."""
     from repro.core import tpu_mapping
     from repro.serving import Engine, ServeConfig
     from repro.serving.sched import ContinuousScheduler, SchedConfig
-    cfg, model, params, _, oracle = serving
+    cfg, model, params, oracle = fused_serving
     reqs = _mk_requests(cfg)
     root = tmp_path / "plans"
     try:
-        # populate the store fault-free, then drop every warm cache so
-        # the chaos run below must read entries back off disk
-        engine0 = Engine(model, params,
-                         ServeConfig(max_new_tokens=10, cache_len=CACHE),
-                         plan_store=PlanStore(root))
-        ContinuousScheduler(
-            engine0, SchedConfig(slots=2, chunk_widths=(8, 32)))
-        tpu_mapping.set_plan_store(None)
-        tpu_mapping.plan_gemm_tiling.cache_clear()
-        set_injector(FaultInjector(            # at= pins one guaranteed
-            [FaultSpec("store.read_io", prob=0.3, at=(0,)),   # hit per
-             FaultSpec("store.corrupt", prob=0.2, at=(1,))],  # site
+        _fill_store_then_drop_caches(model, params, root, reqs)
+        set_injector(FaultInjector(     # the first read fails, the
+            [FaultSpec("store.read_io", at=(0,)),     # second comes
+             FaultSpec("store.corrupt", at=(0,))],    # back corrupt
             seed=7))
         store = PlanStore(root)             # cold in-process cache
         engine = Engine(model, params,
-                        ServeConfig(max_new_tokens=10, cache_len=CACHE),
+                        ServeConfig(max_new_tokens=10,
+                                    cache_len=FUSED_CACHE),
                         plan_store=store)
         sched = ContinuousScheduler(
-            engine, SchedConfig(slots=2, chunk_widths=(8, 32)))
+            engine, SchedConfig(slots=2, chunk_widths=FUSED_WIDTHS))
         results = sched.run(reqs)
     finally:
         set_injector(None)
@@ -507,37 +546,6 @@ def test_traffic_burst_exercises_shedding(serving):
     assert any(r.finish_reason == "rejected" for r in results)
 
 
-def test_prewarm_partial_failure_degrades(serving, tmp_path,
-                                          monkeypatch):
-    """One unplannable shape must not abort scheduler construction:
-    the bad bucket is logged + counted and the rest prewarm."""
-    import repro.planner.batch as batch
-    from repro.serving import Engine, ServeConfig
-    from repro.serving.sched import ContinuousScheduler, SchedConfig
-    cfg, model, params, _, _ = serving
-    real = batch.prewarm_tpu_plans
-    calls = {"n": 0}
-
-    def flaky(shapes, store, **kw):
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise OSError("disk went away")
-        return real(shapes, store, **kw)
-
-    monkeypatch.setattr(batch, "prewarm_tpu_plans", flaky)
-    store = PlanStore(tmp_path / "plans")
-    engine = Engine(model, params,
-                    ServeConfig(max_new_tokens=10, cache_len=CACHE),
-                    plan_store=store)
-    sched = ContinuousScheduler(
-        engine, SchedConfig(slots=2, chunk_widths=(8, 32)))
-    from repro.core import tpu_mapping
-    tpu_mapping.set_plan_store(None)
-    assert calls["n"] > 1                   # kept going past the failure
-    assert sched.prewarmed_plans > 0
-    assert get_registry().snapshot()["sched.prewarm_failures"] == 1
-
-
 # ------------------------------------------------------- router chaos
 
 def test_replica_down_failover_keeps_fidelity(serving):
@@ -596,7 +604,7 @@ def test_replica_down_last_replica_survives(serving):
     assert get_registry().snapshot()["router.replica_downs"] == 1
 
 
-def test_router_store_corruption_keeps_tokens_identical(serving,
+def test_router_store_corruption_keeps_tokens_identical(fused_serving,
                                                         tmp_path):
     """Store chaos under the router: replica-down + corrupt/IO-faulted
     plan reads during a fleet trace — cold re-solves fill the gaps and
@@ -605,31 +613,25 @@ def test_router_store_corruption_keeps_tokens_identical(serving,
     from repro.serving import Engine, ServeConfig
     from repro.serving.router import ReplicaRouter, RouterConfig
     from repro.serving.sched import SchedConfig
-    cfg, model, params, _, oracle = serving
+    cfg, model, params, oracle = fused_serving
     reqs = _mk_requests(cfg, n=6, max_new=6, seed=11)
     for i, r in enumerate(reqs):
         r.arrival_s = 0.0005 * i
     root = tmp_path / "plans"
     try:
-        engine0 = Engine(model, params,
-                         ServeConfig(max_new_tokens=10, cache_len=CACHE),
-                         plan_store=PlanStore(root))
-        from repro.serving.sched import ContinuousScheduler, SchedConfig
-        ContinuousScheduler(
-            engine0, SchedConfig(slots=2, chunk_widths=(8, 32)))
-        tpu_mapping.set_plan_store(None)
-        tpu_mapping.plan_gemm_tiling.cache_clear()
+        _fill_store_then_drop_caches(model, params, root, reqs)
         set_injector(FaultInjector(
-            [FaultSpec("store.read_io", prob=0.3, at=(0,)),
-             FaultSpec("store.corrupt", prob=0.2, at=(1,)),
+            [FaultSpec("store.read_io", at=(0,)),
+             FaultSpec("store.corrupt", at=(0,)),
              FaultSpec("router.replica_down", at=(4,))], seed=7))
         store = PlanStore(root)             # cold in-process cache
         engine = Engine(model, params,
-                        ServeConfig(max_new_tokens=10, cache_len=CACHE),
+                        ServeConfig(max_new_tokens=10,
+                                    cache_len=FUSED_CACHE),
                         plan_store=store)
         router = ReplicaRouter(
             engine, RouterConfig(replicas=2, sched=SchedConfig(
-                slots=2, chunk_widths=(8, 32))))
+                slots=2, chunk_widths=FUSED_WIDTHS)))
         results = router.route_trace(reqs)
     finally:
         set_injector(None)

@@ -1,8 +1,11 @@
 """Continuous-batching scheduler: token-identical to the static oracle
-under arbitrary arrival schedules, zero solver invocations in steady
-state with a plan store installed, bucket/slot unit semantics, and the
-engine satellites (per-step rng split, capacity validation)."""
+under arbitrary arrival schedules, plans resolved only at kernel dispatch
+(never by the scheduler), bucket/slot unit semantics, and the engine
+satellites (per-step rng split, capacity validation)."""
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -264,108 +267,116 @@ def test_scheduler_rejects_recurrent_families():
 
 # ------------------------------------------------- plan-DB integration
 
-def test_zero_solver_invocations_steady_state(setup, tmp_path):
-    """Scheduler construction prewarms every bucketed GEMM tiling
-    through the PlanStore; steady-state traffic then resolves all tile
-    plans with zero solver invocations and zero store misses."""
-    from repro.core import tpu_mapping
-    cfg, model, params, engine, oracle = setup
-    store = PlanStore(tmp_path)
-    engine.plan_store = store
-    try:
-        clock = TraceClock()
-        sched = ContinuousScheduler(
-            engine, SchedConfig(slots=3, chunk_widths=(4, 16)),
-            arch_id="llama3-8b", clock=clock.now)
-        assert sched.prewarmed_plans > 0
-        assert store.puts > 0                 # fresh store was populated
-        misses0 = store.misses
-        reset_solver_stats()
-        rng = np.random.default_rng(1)
-        reqs = [Request(req_id=i,
-                        tokens=rng.integers(0, cfg.vocab, (12,)),
-                        max_new_tokens=4, arrival_s=0.0)
-                for i in range(4)]
-        replay(sched, reqs, clock)
-        assert solver_stats()["calls"] == 0   # zero-solve steady state
-        assert store.misses == misses0        # every lookup a hit
-    finally:
-        engine.plan_store = None
-        tpu_mapping.set_plan_store(None)
+def _requests(cfg, n, prompt_len, max_new, seed):
+    rng = np.random.default_rng(seed)
+    return [Request(req_id=i, tokens=rng.integers(0, cfg.vocab,
+                                                  (prompt_len,)),
+                    max_new_tokens=max_new, arrival_s=0.0)
+            for i in range(n)]
 
 
-def test_fused_mlp_scheduler_prewarms_chains(tmp_path):
-    """A fused-MLP model's scheduler prewarms the bucketed fused chain
-    plans (one per bucket group) alongside the per-GEMM tilings; steady
-    state then runs with zero solver invocations — chain solves included
-    — and stays token-identical to the static oracle of the same
-    model."""
-    import dataclasses
+def _fused_llama3():
+    return dataclasses.replace(get_config("llama3-8b", smoke=True),
+                               fused_mlp=True)
+
+
+@pytest.mark.parametrize("case", ["dense", "moe", "fused"])
+def test_scheduler_construction_plans_nothing(case, tmp_path):
+    """Building a scheduler over a plan-store engine runs no solver and
+    writes nothing to the store — a plan is resolved only where a kernel
+    that dispatches it is traced — and the scheduler then serves the
+    static oracle's tokens."""
     from repro.core import tpu_mapping
-    cfg = dataclasses.replace(get_config("llama3-8b", smoke=True),
-                              fused_mlp=True)
+    arch = "deepseek-moe-16b" if case == "moe" else "llama3-8b"
+    cfg = _fused_llama3() if case == "fused" else get_config(arch,
+                                                              smoke=True)
     model = build_model(cfg)
     params = model.init_params(jax.random.PRNGKey(0))
-    engine = Engine(model, params,
-                    ServeConfig(max_new_tokens=6, cache_len=CACHE))
     oracle = Engine(model, params,
                     ServeConfig(max_new_tokens=6, cache_len=CACHE))
     store = PlanStore(tmp_path)
-    engine.plan_store = store
     try:
+        engine = Engine(model, params,
+                        ServeConfig(max_new_tokens=6, cache_len=CACHE),
+                        plan_store=store)
+        reset_solver_stats()
         clock = TraceClock()
         sched = ContinuousScheduler(
             engine, SchedConfig(slots=2, chunk_widths=(4, 16)),
-            arch_id="llama3-8b", clock=clock.now)
-        assert sched.prewarmed_chains > 0
-        assert store.num_fused() > 0          # fused section populated
-        reset_solver_stats()
-        rng = np.random.default_rng(3)
-        reqs = [Request(req_id=i,
-                        tokens=rng.integers(0, cfg.vocab, (10,)),
-                        max_new_tokens=4, arrival_s=0.0)
-                for i in range(3)]
-        results = replay(sched, reqs, clock)
-        assert solver_stats()["calls"] == 0   # no GEMM or chain solves
-        _check_against_oracle(results, reqs, oracle)
-    finally:
-        engine.plan_store = None
-        tpu_mapping.set_plan_store(None)
-
-
-def test_prewarm_dtype_mismatch_misses(setup, tmp_path, monkeypatch):
-    """Plan identity includes the dtype-rescaled VMEM capacity: plans
-    prewarmed under the wrong dtype_bytes miss at dispatch time; the
-    engine's default (its compute dtype) hits."""
-    from repro.capture import plan as capture_plan
-    from repro.core import tpu_mapping
-    cfg, model, params, engine, oracle = setup
-    monkeypatch.setattr(capture_plan, "serving_capture_shapes",
-                        lambda *a, **k: [(64, 64, 64)])
-    store = PlanStore(tmp_path)
-    engine.plan_store = store
-    try:
-        assert engine.dispatch_dtype_bytes == 4       # f32 smoke model
-        # prewarm under bf16 capacity -> f32 dispatch must miss + solve
-        engine.prewarm_plans("llama3-8b", 1, 8, dtype_bytes=2)
-        tpu_mapping.plan_gemm_tiling.cache_clear()
-        misses0, puts0 = store.misses, store.puts
-        reset_solver_stats()
-        tpu_mapping.plan_gemm_tiling(64, 64, 64, dtype_bytes=4)
-        assert store.misses > misses0
-        assert store.puts > puts0             # healed by a fresh solve
-        assert solver_stats()["calls"] > 0
-        # prewarm under the engine default -> dispatch hits, no solve
-        engine.prewarm_plans("llama3-8b", 1, 8)
-        tpu_mapping.plan_gemm_tiling.cache_clear()
-        misses1, hits1 = store.misses, store.hits
-        reset_solver_stats()
-        tpu_mapping.plan_gemm_tiling(64, 64, 64, dtype_bytes=4)
-        assert store.misses == misses1
-        assert store.hits > hits1
+            arch_id=arch, clock=clock.now)
         assert solver_stats()["calls"] == 0
+        assert store.puts == 0
+        reqs = _requests(cfg, 3, 10, 4, seed=3)
+        results = replay(sched, reqs, clock)
     finally:
-        engine.plan_store = None
+        tpu_mapping.set_plan_store(None)
+    _check_against_oracle(results, reqs, oracle)
+
+
+def test_fused_mlp_plans_resolve_at_dispatch(tmp_path):
+    """A fused-MLP model resolves its chain plans when its programs are
+    traced.  The first serve fills the store's fused section; a fresh
+    engine over the same store directory, with no plan cached and no
+    program traced (a restarted process), serves the same requests from
+    the store with zero solver invocations and the oracle's tokens.
+    Plans are keyed by the dispatch dtype: the f32 model's chains hit,
+    the same chain under bf16 misses and solves."""
+    from repro.core import tpu_mapping
+    cfg = _fused_llama3()
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    oracle = Engine(model, params,
+                    ServeConfig(max_new_tokens=6, cache_len=CACHE))
+    serve = ServeConfig(max_new_tokens=6, cache_len=CACHE)
+    sched_cfg = SchedConfig(slots=2, chunk_widths=(4, 16))
+    reqs = _requests(cfg, 3, 10, 4, seed=5)
+    try:
+        jax.clear_caches()              # trace the fused kernel anew
+        store = PlanStore(tmp_path)
+        first = ContinuousScheduler(
+            Engine(model, params, serve, plan_store=store),
+            sched_cfg).run(reqs)
+        assert store.num_fused() > 0
+        tpu_mapping.set_plan_store(None)
+        jax.clear_caches()
+        store = PlanStore(tmp_path)
+        engine = Engine(model, params, serve, plan_store=store)
+        reset_solver_stats()
+        results = ContinuousScheduler(engine, sched_cfg).run(reqs)
+        assert solver_stats()["calls"] == 0   # no GEMM or chain solves
+        assert store.hits > 0 and store.misses == 0 and store.puts == 0
+        assert jnp.dtype(cfg.compute_dtype).itemsize == 4
+        tpu_mapping.plan_fused_mlp(2, cfg.d_ff, cfg.d_model,
+                                   dtype_bytes=2)
+        assert store.misses > 0
+        assert solver_stats()["calls"] > 0
+    finally:
+        tpu_mapping.set_plan_store(None)
+    _check_against_oracle(results, reqs, oracle)
+    assert [r.tokens for r in first] == [r.tokens for r in results]
+
+
+def test_engine_installs_its_plan_store(setup, tmp_path):
+    """``Engine(plan_store=s)`` installs ``s`` for dispatch: a GOMA
+    kernel traced afterwards plans through it."""
+    from repro.core import tpu_mapping
+    from repro.kernels.ops import gemm
+    _, model, params, _, _ = setup
+    store = PlanStore(tmp_path)
+    try:
+        Engine(model, params, ServeConfig(cache_len=CACHE),
+               plan_store=store)
+        assert tpu_mapping.get_plan_store() is store
+        jax.clear_caches()              # gemm traces anew, so it plans
+        a = jnp.asarray(np.random.default_rng(0).normal(size=(24, 40)),
+                        jnp.float32)
+        b = jnp.asarray(np.random.default_rng(1).normal(size=(40, 56)),
+                        jnp.float32)
+        np.testing.assert_allclose(np.asarray(gemm(a, b)),
+                                   np.asarray(a) @ np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+        assert store.puts > 0 and len(store) > 0
+    finally:
         tpu_mapping.set_plan_store(None)
 
 
