@@ -32,6 +32,10 @@ Conventions used across the repo:
                                   tracer each is a ``jit.compile``
                                   event with its ``fun_name``)
   sched.*                         scheduler ticks / chunks / tokens
+  sched.decode.reads              blocking device->host reads of the
+                                  slot decode's ticks: one a tick, so
+                                  equal to sched.decode_steps (the
+                                  speculative verify's are not counted)
   sched.spec.{rounds,drafted,accepted}   scheduler-side speculative
                                   verify rounds and acceptance tallies
   sched.prefix_tokens_reused      prompt tokens grafted from the KV
@@ -73,9 +77,11 @@ Spans (``obs.tracing``) of one scheduler tick, each with ``tick=``:
     sched.graft                   first-token sample, ``insert_row``,
                                   prefix insert
     sched.decode_batch            one decode step over the slot pool
-      sched.decode.dispatch       inputs, ``decode_slots``, last logits
-      sched.decode.guard          ``_guard_rows``' finiteness read
-      sched.decode.sample         ``_sample_rows``' sampling read
+      sched.decode.dispatch       inputs, ``decode_slots``, then
+                                  ``pick_rows`` (guard and sampling)
+      sched.decode.read           the tick's one blocking read: the
+                                  picked tokens (counted as
+                                  ``sched.decode.reads``)
     sched.emit                    streaming the step's tokens
   sched.request                   detached, admit to finish (``req_id``)
 """

@@ -8,13 +8,15 @@ serving integration test.
 
 Beyond the static ``generate`` loop, the engine exposes its *step-level*
 primitives — ``new_cache`` / ``prefill_chunk`` / ``decode_slots`` /
-``insert_row`` / ``sample`` — which the continuous-batching scheduler
-(``serving.sched``) composes into an admission/prefill/decode loop.  All
-of them run ``model.decode_step``, so the number of distinct compiled
-programs is bounded by the number of chunk widths in use (see
-sched.BucketSpec), not by traffic.  Each primitive jits it under its own
-name (``jit_prefill_chunk``, ``jit_decode_slots``, ``jit_insert_row``,
-``jit_verify_step``), so a profile tells their device time apart.
+``insert_row`` / ``sample`` / ``pick_rows`` — which the
+continuous-batching scheduler (``serving.sched``) composes into an
+admission/prefill/decode loop.  The model steps all run
+``model.decode_step``, so the number of distinct compiled programs is
+bounded by the number of chunk widths in use (see sched.BucketSpec), not
+by traffic.  Each primitive jits under its own name
+(``jit_prefill_chunk``, ``jit_decode_slots``, ``jit_insert_row``,
+``jit_verify_step``, ``jit_pick_rows``), so a profile tells their device
+time apart.
 """
 from __future__ import annotations
 
@@ -44,6 +46,10 @@ def gumbel_argmax(logits, temperature: float, key):
     continuous scheduler (token-identity depends on them agreeing)."""
     g = jax.random.gumbel(key, logits.shape)
     return jnp.argmax(logits / temperature + g, axis=-1).astype(jnp.int32)
+
+
+# ``Engine.pick_rows``' token for a row whose logits are not all finite
+NOT_FINITE = -1
 
 
 def donate_default() -> bool:
@@ -127,6 +133,26 @@ class Engine:
 
         self._verify = jax.jit(verify_step)
 
+        # the slot decode's guard and sampling as one program, so a tick
+        # dispatches it right behind the decode and reads back one (B,)
+        # vector.  ``self.sample`` is looked up when the program is
+        # traced: a patched ``Engine.sample`` is traced into it.
+        def pick_rows(logits, temperature, base_key, req_ids, token_idx):
+            last = logits[:, -1]
+            ok = jnp.isfinite(last)
+            last = jnp.where(ok, last, -jnp.inf)
+            if base_key is None:
+                tok = self.sample(last, None)
+            else:
+                def one(rid, idx, row):
+                    key = jax.random.fold_in(
+                        jax.random.fold_in(base_key, rid), idx)
+                    return gumbel_argmax(row, temperature, key)
+                tok = jax.vmap(one)(req_ids, token_idx, last)
+            return jnp.where(jnp.all(ok, axis=-1), tok, NOT_FINITE)
+
+        self._pick_rows = _named_jit(pick_rows, "pick_rows")
+
     # ----------------------------------------------------- step-level API
     def new_cache(self, batch: int):
         """Fresh static cache for `batch` rows at cfg.cache_len."""
@@ -177,6 +203,22 @@ class Engine:
     def sample(self, logits, rng):
         """Greedy/temperature sampling (row-wise; rng may be None)."""
         return self._sample(logits, rng)
+
+    def pick_rows(self, logits, temperature: float = 0.0, base_key=None,
+                  req_ids=None, token_idx=None):
+        """Guard and sample a slot decode's logits (B, 1, V) in one
+        program (``jit_pick_rows``), returning (B,) int32: each row's
+        next token, or ``NOT_FINITE`` where the row holds a NaN or an
+        Inf.  Non-finite entries are masked to -inf before sampling.
+        Without ``base_key`` a row is sampled by ``sample`` (greedy);
+        with it, row b is the Gumbel-max at ``temperature`` under
+        ``fold_in(fold_in(base_key, req_ids[b]), token_idx[b])``, the
+        key of that request's token alone."""
+        if base_key is None:
+            return self._pick_rows(logits, None, None, None, None)
+        return self._pick_rows(logits, jnp.float32(temperature), base_key,
+                               jnp.asarray(req_ids, jnp.uint32),
+                               jnp.asarray(token_idx, jnp.uint32))
 
     def validate_capacity(self, prompt_len: int, max_new_tokens: int, *,
                           prefix_len: int = 0, lookahead: int = 0) -> None:

@@ -44,7 +44,7 @@ from ...obs.registry import get_registry
 from ...obs.tracing import get_tracer
 from ...obs.tracing import span as _span
 from ...obs.tracing import trace_event
-from ..engine import Engine, gumbel_argmax
+from ..engine import NOT_FINITE, Engine, gumbel_argmax
 from .buckets import BucketSpec, Chunk
 from .metrics import ServingMetrics
 from .requests import Request, RequestResult, RequestState
@@ -278,9 +278,10 @@ class ContinuousScheduler:
         Observability: the tick is one ``sched.tick`` span (a step on
         the profiler's clock) with children ``sched.admit``,
         ``sched.prefill_chunk``, ``sched.graft``, ``sched.decode_batch``
-        (itself split into ``sched.decode.dispatch`` / ``.guard`` /
-        ``.sample``) and ``sched.emit``, each with ``tick=``; admission
-        opens a detached per-request ``sched.request`` span that
+        (itself split into ``sched.decode.dispatch`` and
+        ``sched.decode.read``, the tick's one blocking read of the
+        decode's result) and ``sched.emit``, each with ``tick=``;
+        admission opens a detached per-request ``sched.request`` span that
         ``_emit`` closes at finish.  Registry counters mirror the
         ``ServingMetrics`` tick accounting under ``sched.*``."""
         wall0 = time.perf_counter()
@@ -356,19 +357,18 @@ class ContinuousScheduler:
                     positions = jnp.asarray(self._pos)
                     logits, self.slot_cache = self.engine.decode_slots(
                         self.slot_cache, tokens, positions)
-                    last = logits[:, -1]
                     hit = inject("kernel.nan_row")
                     if hit is not None:     # chaos: poison one active
                         #                         row's logits in place
                         victim = active[hit.index % len(active)].idx
                         bad = float(hit.payload.get("value",
                                                     float("nan")))
-                        last = last.at[victim].set(bad)
-                with _span("sched.decode.guard", tick=tick):
-                    active = self._guard_rows(last, active)
-                with _span("sched.decode.sample", tick=tick):
-                    nxt = (self._sample_rows(last, active) if active
-                           else None)
+                        logits = logits.at[victim, -1].set(bad)
+                    picked = self._pick_rows(logits, active)
+                with _span("sched.decode.read", tick=tick):
+                    nxt = np.asarray(picked)
+                _REG.inc("sched.decode.reads")
+                active = self._evict_not_finite(nxt, active)
             now = self.clock()
             with _span("sched.emit", tick=tick):
                 for slot in active:
@@ -493,20 +493,21 @@ class ContinuousScheduler:
         return active
 
     # ------------------------------------------------------ fault isolation
-    def _guard_rows(self, last, active: list[Slot]) -> list[Slot]:
-        """Evict active slots whose logits row went NaN/Inf — a poisoned
-        row must never reach sampling (Gumbel/argmax over NaN silently
-        picks an arbitrary token).  Only the poisoned rows pay: slot rows
-        are batch-independent, so the survivors' streams are untouched
-        and stay token-identical to the fault-free oracle."""
-        finite = np.asarray(jnp.all(jnp.isfinite(last), axis=-1))
-        bad = [s for s in active if not finite[s.idx]]
+    def _evict_not_finite(self, nxt: np.ndarray,
+                          active: list[Slot]) -> list[Slot]:
+        """Evict active slots whose logits row went NaN/Inf
+        (``NOT_FINITE`` in ``nxt``, the picked tokens) — a poisoned row
+        must never be emitted (Gumbel/argmax over NaN silently picks an
+        arbitrary token).  Only the poisoned rows pay: slot rows are
+        batch-independent, so the survivors' streams are untouched and
+        stay token-identical to the fault-free oracle."""
+        bad = [s for s in active if nxt[s.idx] == NOT_FINITE]
         if not bad:
             return active
         now = self.clock()
         for slot in bad:
             self._evict_errored(slot, now)
-        return [s for s in active if finite[s.idx]]
+        return [s for s in active if nxt[s.idx] != NOT_FINITE]
 
     def _evict_errored(self, slot: Slot, now: float, *,
                        counter: str = "errors.sched.nan_row") -> None:
@@ -636,30 +637,24 @@ class ContinuousScheduler:
             return int(jnp.argmax(row))
         return int(gumbel_argmax(row, self.cfg.temperature, key))
 
-    def _sample_rows(self, logits, active: list[Slot]) -> np.ndarray:
-        """Sample every row of a decode step's last-token logits.
+    def _pick_rows(self, logits, active: list[Slot]):
+        """Dispatch the guard and sampling of a decode step's logits as
+        one program (``Engine.pick_rows``); nothing is read back here.
 
         Greedy is batch-wide argmax (bit-identical to the oracle's).
-        Temperature uses one key per (request, token index) — the same
-        fold_in schedule as ``Engine.generate`` — vmapped over rows.
-
-        Non-finite entries are masked to -inf first: Gumbel noise added
-        to a NaN logit is NaN, and ``argmax`` over NaNs silently returns
-        an arbitrary (implementation-defined) token — a poisoned row
-        must never turn into a plausible-looking sample.  Rows that are
-        *entirely* non-finite are evicted upstream (``_guard_rows``)
-        before sampling; the mask here keeps a stray ±inf/NaN element in
-        an otherwise-healthy row from hijacking its argmax."""
-        logits = jnp.where(jnp.isfinite(logits), logits, -jnp.inf)
+        Temperature gives each active row the key of its (request, token
+        index) — the same fold_in schedule as ``Engine.generate`` and
+        ``_step_key`` — folded inside the program.  Every input has a
+        fixed shape at the slot count, so the program compiles once."""
         if self.cfg.temperature <= 0.0:
-            return np.asarray(self.engine.sample(logits, None))
-        keys = [jax.random.PRNGKey(0)] * len(self.slots)
+            return self.engine.pick_rows(logits)
+        req_ids = np.zeros((len(self.slots),), np.uint32)
+        token_idx = np.zeros((len(self.slots),), np.uint32)
         for slot in active:
-            keys[slot.idx] = self._step_key(slot.req, slot.emitted)
-        temp = self.cfg.temperature
-        return np.asarray(jax.vmap(
-            lambda key, row: gumbel_argmax(row, temp, key))(
-                jnp.stack(keys), logits))
+            req_ids[slot.idx] = slot.req.req_id
+            token_idx[slot.idx] = slot.emitted
+        return self.engine.pick_rows(logits, self.cfg.temperature,
+                                     self._base_key, req_ids, token_idx)
 
     # ------------------------------------------------------------- driving
     def run(self, requests=None, *, max_steps: int = 1_000_000

@@ -289,8 +289,7 @@ class TestSchedulerSpans:
                      "sched.decode_batch": "sched.tick",
                      "sched.emit": "sched.tick",
                      "sched.decode.dispatch": "sched.decode_batch",
-                     "sched.decode.guard": "sched.decode_batch",
-                     "sched.decode.sample": "sched.decode_batch"}
+                     "sched.decode.read": "sched.decode_batch"}
         assert set(parent_of) <= set(names)
         for sp in tr.spans:
             if sp.name in parent_of:
@@ -302,8 +301,7 @@ class TestSchedulerSpans:
         assert names.count("sched.graft") == 2
         for db in tr.by_name("sched.decode_batch"):
             assert [c.name for c in tr.children(db)] == [
-                "sched.decode.dispatch", "sched.decode.guard",
-                "sched.decode.sample"]
+                "sched.decode.dispatch", "sched.decode.read"]
         # what the benchmark reads keeps its names and attributes
         assert [s.attrs["tick"] for s in tr.by_name("sched.tick")] == \
             list(range(sched.metrics.steps))
@@ -358,7 +356,7 @@ class TestSchedulerSpans:
             [t[3]["step_num"] for t in ticks]
         subs = {"sched.admit", "sched.prefill_chunk", "sched.graft",
                 "sched.decode_batch", "sched.decode.dispatch",
-                "sched.decode.guard", "sched.decode.sample", "sched.emit"}
+                "sched.decode.read", "sched.emit"}
         assert subs <= {e[0] for e in events}
         for name, t0, t1, stats in events:
             if name in subs:

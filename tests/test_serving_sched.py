@@ -14,6 +14,7 @@ from repro.core.solver import reset_solver_stats, solver_stats
 from repro.models import build_model
 from repro.planner import PlanStore
 from repro.serving import Engine, ServeConfig
+from repro.serving.engine import gumbel_argmax
 from repro.serving.sched import (BucketSpec, ContinuousScheduler, Request,
                                  SchedConfig, SlotManager, TraceClock,
                                  TrafficConfig, poisson_trace, replay)
@@ -254,6 +255,79 @@ def test_streaming_callbacks_and_metrics(setup):
     summ = sched.metrics.summary()
     assert summ["requests"] == 2
     assert summ["total_generated_tokens"] == 8
+
+
+def test_temperature_samples_use_per_request_keys(setup, monkeypatch):
+    """With temperature, every token a decode step emits is the
+    Gumbel-max of its row's logits under the key built from the
+    request alone: ``fold_in(fold_in(PRNGKey(rng_seed), req_id),
+    token_idx)``, bit for bit, whichever slot the request holds."""
+    cfg, model, params, engine, _ = setup
+    temp, seed = 1.5, 123
+    rng = np.random.default_rng(21)
+    reqs = [Request(req_id=rid, tokens=rng.integers(0, cfg.vocab, (3 + i,)),
+                    max_new_tokens=5 + i, arrival_s=0.02 * i)
+            for i, rid in enumerate((4, 0, 2**31 + 5, 17, 9))]
+    clock = TraceClock()
+    sched = ContinuousScheduler(
+        engine, SchedConfig(slots=3, chunk_widths=(4, 16), temperature=temp,
+                            rng_seed=seed), clock=clock.now)
+    rows = []                           # (req_id, token index, logits)
+    decode = engine.decode_slots
+
+    def recording(cache, tokens, positions):
+        logits, cache = decode(cache, tokens, positions)
+        last = np.asarray(logits[:, -1])
+        pf = sched._prefill
+        rows.extend((s.req.req_id, s.emitted, last[s.idx])
+                    for s in sched.slots.busy()
+                    if pf is None or s is not pf.slot)
+        return logits, cache
+    monkeypatch.setattr(engine, "decode_slots", recording)
+    by_id = {r.req_id: r.tokens for r in replay(sched, reqs, clock)}
+    assert len(rows) == sum(len(t) - 1 for t in by_id.values())
+    base = jax.random.PRNGKey(seed)
+    greedy = 0
+    for rid, j, row in rows:
+        key = jax.random.fold_in(jax.random.fold_in(base, rid), j)
+        want = int(gumbel_argmax(jnp.asarray(row), temp, key))
+        assert by_id[rid][j] == want, (rid, j)
+        greedy += want == int(np.argmax(row))
+    assert greedy < len(rows)           # the noise did pick other tokens
+
+
+def test_a_decode_tick_reads_the_device_once(setup):
+    """A decode tick makes one blocking device->host read: the guard
+    and the sampling run in one program dispatched behind the decode.
+    That program compiles once per slot count, whatever the number of
+    active rows, and the streams stay the oracle's."""
+    from repro.obs.registry import get_registry
+    from repro.obs.tracing import Tracer, set_tracer
+    cfg, model, params, _, oracle = setup
+    engine = Engine(model, params, ServeConfig(max_new_tokens=10,
+                                               cache_len=CACHE))
+    rng = np.random.default_rng(5)
+    reqs = [Request(req_id=i, tokens=rng.integers(0, cfg.vocab, (3 + 2 * i,)),
+                    max_new_tokens=3 + i, arrival_s=0.03 * i)
+            for i in range(5)]
+    tr = Tracer()
+    set_tracer(tr)
+    try:
+        for slots in (2, 3, 2):
+            clock = TraceClock()
+            sched = ContinuousScheduler(
+                engine, SchedConfig(slots=slots, chunk_widths=(4, 16)),
+                clock=clock.now)
+            _check_against_oracle(replay(sched, reqs, clock), reqs, oracle)
+    finally:
+        set_tracer(None)
+    reg = get_registry()
+    assert reg.get("sched.decode.reads") == reg.get("sched.decode_steps") > 0
+    assert len(tr.by_name("sched.decode.read")) == \
+        reg.get("sched.decode_steps")
+    picks = [e for e in tr.by_name("jit.compile")
+             if "pick_rows" in e.attrs["fun_name"]]
+    assert len(picks) == 2, [e.attrs["fun_name"] for e in picks]
 
 
 def test_scheduler_rejects_recurrent_families():
